@@ -1,4 +1,4 @@
-"""Roofline analysis from dry-run records (TPU v5e constants).
+"""Roofline analysis from dry-run records (peaks of the record's chip).
 
     compute term    = HLO_FLOPs / (chips × 197 TFLOP/s)
     memory term     = HLO_bytes / (chips × 819 GB/s)
@@ -20,7 +20,7 @@ from typing import Optional
 
 from repro import configs
 from repro.configs.shapes import SHAPES
-from repro.core.costmodel import TPU_V5E
+from repro.core import costmodel
 
 
 def model_flops(arch: str, shape_name: str) -> float:
@@ -46,9 +46,11 @@ def roofline_row(rec: dict, chips: Optional[int] = None) -> Optional[dict]:
     bytes_dev = rec["cost"].get("bytes",
                                 rec["cost"].get("bytes accessed", 0.0))
     coll_dev = rec["collectives"]["total"]
-    t_compute = flops_dev / TPU_V5E.flops
-    t_memory = bytes_dev / TPU_V5E.hbm_bw
-    t_coll = coll_dev / TPU_V5E.ici_bw
+    # the dry run compiles for a production v5e mesh unless it says else
+    spec = costmodel.tpu_spec(rec.get("device_kind", "TPU v5 lite"))
+    t_compute = flops_dev / spec.flops
+    t_memory = bytes_dev / spec.hbm_bw
+    t_coll = coll_dev / spec.ici_bw
     dom = max(("compute", t_compute), ("memory", t_memory),
               ("collective", t_coll), key=lambda kv: kv[1])
     mf = model_flops(rec["arch"], rec["shape"])

@@ -28,6 +28,7 @@ from repro.core import quant
 from repro.core.quant import quantize
 from repro.kernels import planning
 from repro.kernels.gemm import gemm
+from repro.launch import compile_cache
 
 BENCH_FORMAT = quant.DEFAULT_FORMAT      # set by main() from --format
 
@@ -114,11 +115,11 @@ def bench_kernel_walltime():
                 if strat == baseline:
                     continue
                 t = _time(lambda s=strat: planning.execute(
-                    plans[s], x, qt, interpret=True))
+                    plans[s], x, qt))
                 print(f"kernels/{strat}/N{N}_K{K}_M{M},{t:.1f},"
                       f"{t / t_base:.2f}")
             wd = w.astype(jnp.bfloat16)
-            t_g = _time(lambda: gemm(x, wd, interpret=True))
+            t_g = _time(lambda: gemm(x, wd))
             print(f"kernels/gemm_bf16/N{N}_K{K}_M{M},{t_g:.1f},"
                   f"{t_g / t_base:.2f}")
 
@@ -233,8 +234,7 @@ def bench_formats(out_path: str = "BENCH_formats.json") -> dict:
                 x = jax.random.normal(key, (M, K), jnp.bfloat16)
                 problem = planning.MatmulProblem.from_operands(x, qt)
                 plan = planning.plan_matmul(problem, strategy=fused_strategy)
-                t_us = _time(lambda: planning.execute(
-                    plan, x, qt, interpret=True))
+                t_us = _time(lambda: planning.execute(plan, x, qt))
                 moved = qt.nbytes_packed() + x.nbytes + M * N * 2
                 gbps = moved / (t_us * 1e-6) / 1e9
                 picked = planning.plan_matmul(problem, use_cache=False)
@@ -475,24 +475,12 @@ def bench_paged_attn(out_path: str = "BENCH_paged_attn.json") -> dict:
         kk, kv_ = jax.random.split(jax.random.fold_in(key, ctx))
         k = jax.random.normal(kk, (B, ctx, Hkv, D), jnp.float32)
         v = jax.random.normal(kv_, (B, ctx, Hkv, D), jnp.float32)
-        kq, ks = q.kv_quantize(k, fmt)
-        vq, vs = q.kv_quantize(v, fmt)
-
-        def pack(x, tail):
-            full = jnp.zeros((nb, ps) + tail, x.dtype)
-            return full.at[1:].set(x.reshape(B * T, ps, *tail))
-
-        pool = kvc.PagedKVCache(
-            k_pool=pack(kq, (Hkv, D)), v_pool=pack(vq, (Hkv, D)),
-            page_pos=jnp.full((nb, ps), -1, jnp.int32).at[1:].set(
-                jnp.tile(jnp.arange(ctx, dtype=jnp.int32).reshape(T, ps),
-                         (B, 1))),
-            k_scale=None if ks is None else pack(ks, (Hkv,)),
-            v_scale=None if vs is None else pack(vs, (Hkv,)))
         tables = (1 + jnp.arange(B * T, dtype=jnp.int32)).reshape(B, T)
-        ring = attention.KVCache(
-            k=k, v=v, pos=jnp.tile(jnp.arange(ctx, dtype=jnp.int32),
-                                   (B, 1)))
+        positions = jnp.tile(jnp.arange(ctx, dtype=jnp.int32), (B, 1))
+        pool = kvc.scatter_chunks(
+            kvc.init_pool(nb, ps, Hkv, D, jnp.float32, kv_format=fmt_name),
+            tables, k, v, positions, cache_len=ctx, fmt=fmt)
+        ring = attention.KVCache(k=k, v=v, pos=positions)
         pos = jnp.full((B,), ctx - 1, jnp.int32)
         qv = jax.random.normal(jax.random.fold_in(key, 1),
                                (B, Hq, D), jnp.float32)
@@ -860,6 +848,7 @@ def main(argv=None) -> None:
     ap.add_argument("--out", default="BENCH_quickstart.json",
                     help="--quick output path")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     global BENCH_FORMAT
     BENCH_FORMAT = quant.get_format(args.format).name

@@ -31,7 +31,7 @@ print(f"planned: {plan.strategy} split_k={plan.split_k} "
 #    same execute, no dispatcher (format-incompatible ones are refused).
 for strategy in planning.strategies_for_format(qt.format.name):
     p = planning.plan_matmul(problem, strategy=strategy)
-    y = planning.execute(p, x, qt, interpret=True)
+    y = planning.execute(p, x, qt)
     err = float(jnp.abs(y - x @ dequantize(qt)).max())
     print(f"  strategy={strategy:10s} out={y.shape} max|err|={err:.2e}")
 

@@ -42,15 +42,38 @@ class AscendSpec:
 
 
 @dataclasses.dataclass(frozen=True)
-class TPUv5eSpec:
-    flops: float = 197e12             # bf16 FLOP/s per chip
-    hbm_bw: float = 819e9             # bytes/s per chip
-    ici_bw: float = 50e9              # bytes/s per link
-    vmem_bytes: int = 128 * 2 ** 20
+class TPUSpec:
+    flops: float                      # bf16 FLOP/s per chip
+    hbm_bw: float                     # bytes/s per chip
+    ici_bw: float                     # bytes/s per link
+    vmem_bytes: int
+    cores_per_chip: int               # TensorCores a "parallel" grid axis
+                                      # of one kernel can spread over
 
 
 ASCEND = AscendSpec()
-TPU_V5E = TPUv5eSpec()
+
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of interconnect over 4 links, one TensorCore).
+TPU_PEAKS = {
+    "TPU v5 lite": TPUSpec(flops=197e12, hbm_bw=819e9, ici_bw=50e9,
+                           vmem_bytes=128 * 2 ** 20, cores_per_chip=1),
+}
+TPU_V5E = TPU_PEAKS["TPU v5 lite"]
+
+
+def tpu_spec(device_kind: str) -> TPUSpec:
+    """Peaks of one chip of ``device_kind``; an unlisted kind is an error,
+    never a default — a plan or a roofline share against the wrong chip's
+    peaks would be silently wrong."""
+    try:
+        return TPU_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for TPU device_kind {device_kind!r}; "
+            f"known kinds: {sorted(TPU_PEAKS)} (add one to "
+            f"core/costmodel.TPU_PEAKS with its source)") from None
 
 
 def _ceil_div(a: int, b: int) -> int:
@@ -159,7 +182,7 @@ def w4a16_speedup_ascend(M: int, N: int, K: int,
 # ---------------------------------------------------------------------------
 
 def w4a16_time_tpu_fused(M: int, N: int, K: int,
-                         spec: TPUv5eSpec = TPU_V5E) -> float:
+                         spec: TPUSpec = TPU_V5E) -> float:
     """Fused kernel: INT4 weights cross HBM once; dequant lives in VMEM.
     No round-trip term — the 'direct vector→cube data path'."""
     traffic = 2 * M * K + 0.5 * K * N + 2 * M * N
@@ -167,7 +190,7 @@ def w4a16_time_tpu_fused(M: int, N: int, K: int,
 
 
 def w4a16_time_tpu_decoupled(M: int, N: int, K: int, *, split_k: int = 1,
-                             spec: TPUv5eSpec = TPU_V5E) -> float:
+                             spec: TPUSpec = TPU_V5E) -> float:
     """Paper-faithful pipeline on TPU: workspace round-trips through HBM
     (TPU has no shared L2 between kernels — the penalty is *worse* than
     Ascend's, which is exactly why the fused kernel is the right port)."""
@@ -179,13 +202,13 @@ def w4a16_time_tpu_decoupled(M: int, N: int, K: int, *, split_k: int = 1,
 
 
 def fp16_time_tpu(M: int, N: int, K: int,
-                  spec: TPUv5eSpec = TPU_V5E) -> float:
+                  spec: TPUSpec = TPU_V5E) -> float:
     traffic = 2 * M * K + 2 * K * N + 2 * M * N
     return max((2 * M * N * K) / spec.flops, traffic / spec.hbm_bw)
 
 
 def w8a16_time_tpu_fused(M: int, N: int, K: int,
-                         spec: TPUv5eSpec = TPU_V5E) -> float:
+                         spec: TPUSpec = TPU_V5E) -> float:
     """Fused per-channel INT8 kernel: int8 weight rows cross HBM once
     (K·N bytes, half of fp16) plus one fp32 scale row; dequant in VMEM."""
     traffic = 2 * M * K + 1.0 * K * N + 4 * N + 2 * M * N
@@ -193,7 +216,7 @@ def w8a16_time_tpu_fused(M: int, N: int, K: int,
 
 
 def w4a8_time_tpu_fused(M: int, N: int, K: int, *, group: int = 128,
-                        spec: TPUv5eSpec = TPU_V5E) -> float:
+                        spec: TPUSpec = TPU_V5E) -> float:
     """Fused W4A8 kernel: int8 activations (M·K bytes, half of fp16),
     packed int4 weights (K·N/2) + fp32 group scales; int8×int8 MXU dots at
     twice the bf16 MAC rate (v5e int8 peak is 2× bf16)."""
@@ -261,7 +284,7 @@ def paged_attn_bytes(path: str, B: int, Hq: int, Hkv: int, D: int,
 def attn_decode_time_tpu(path: str, B: int, Hq: int, Hkv: int, D: int,
                          ctx: int, *, quantized: bool, act_bytes: int = 2,
                          kv_partitions: int = 1, q_len: int = 1,
-                         spec: TPUv5eSpec = TPU_V5E) -> float:
+                         spec: TPUSpec = TPU_V5E) -> float:
     """Roofline time of one attention step (``q_len`` queries per row):
     QK^T + PV flops vs the path's HBM traffic. Decode and chunk-sized
     prefill are both firmly bandwidth-bound (arithmetic intensity ~q_len

@@ -7,7 +7,8 @@ by the TPU v5e cost model under a hard VMEM-budget constraint — the same
   * VMEM working set (double-buffered inputs + fp32 accumulator) must fit;
   * MXU dims want 128-alignment (lane width) and big K blocks amortize the
     per-block dequant;
-  * grid shape balances against megacore parallelism via the wave model.
+  * grid shape balances against the chip's TensorCores via the wave
+    model (``common.target_spec``; one on a v5e).
 
 Returns (block_m, block_n, block_k, split_k) for a given GEMM shape.
 """
@@ -16,7 +17,6 @@ from __future__ import annotations
 import functools
 from typing import Tuple
 
-from repro.core.costmodel import TPU_V5E
 from repro.kernels import common
 
 # Both re-exported from kernels/common.py — the one budget and working-set
@@ -25,22 +25,23 @@ from repro.kernels import common
 VMEM_BUDGET = common.VMEM_BUDGET
 vmem_working_set = common.vmem_working_set
 
-NUM_PARALLEL = 2                   # TensorCores per chip (megacore)
-
 
 def _score(M, N, K, bm, bn, bk, split_k):
-    """Estimated kernel time: HBM traffic + dequant + wave quantization."""
+    """Estimated kernel time: HBM traffic + dequant + wave quantization
+    over the target chip's TensorCores."""
+    spec = common.target_spec()
+    cores = spec.cores_per_chip
     ks = K // split_k
     n_m, n_n, n_k = -(-M // bm), -(-N // bn), ks // bk
     tiles = n_m * n_n * split_k
-    waves = -(-tiles // NUM_PARALLEL)
-    eff = tiles / (waves * NUM_PARALLEL)
+    waves = -(-tiles // cores)
+    eff = tiles / (waves * cores)
     flops = 2 * M * N * K
-    t_compute = flops / (TPU_V5E.flops * eff)
+    t_compute = flops / (spec.flops * eff)
     # x re-read per N tile; packed W re-read per M tile; partials out
     traffic = (2 * M * K * n_n + 0.5 * K * N * n_m
                + (4 * split_k if split_k > 1 else 2) * M * N)
-    t_mem = traffic / TPU_V5E.hbm_bw
+    t_mem = traffic / spec.hbm_bw
     return max(t_compute, t_mem)
 
 
@@ -56,7 +57,8 @@ def autotune_w4a16(M: int, N: int, K: int,
         for bk in (256, 512, 1024, 2048):
             if K % bk or not (bk % group == 0 or group % bk == 0):
                 continue
-            if vmem_working_set(bm, bk=bk, bn=bn, group=group) > VMEM_BUDGET:
+            if vmem_working_set(bm, bk=bk, bn=bn, group=group,
+                                k=K) > VMEM_BUDGET:
                 continue
             for s in (1, 2, 4, 8):
                 if K % (s * bk) and (K // s) % bk:

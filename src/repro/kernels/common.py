@@ -6,34 +6,48 @@ MXU dimensions 128-aligned.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.core import costmodel
 
 LANE = 128          # TPU lane width — minor dim of every block
 SUBLANE = 8         # fp32 sublane; bf16 is 16 but 8 keeps blocks legal
 
-# The one VMEM budget (leave headroom off the ~128MB v5e VMEM). Both the
-# autotuner's candidate ranking and the template's block chooser
+# The one VMEM budget: Mosaic's default scoped-VMEM limit on TPU v5e. A
+# kernel whose blocks and temporaries exceed it is refused at compile time
+# ("Ran out of memory in memory space vmem"). Both the autotuner's
+# candidate ranking and the template's block chooser
 # (template.choose_blocks) enforce it through vmem_working_set below.
-VMEM_BUDGET = 96 * 1024 * 1024
+VMEM_BUDGET = 16 * 1024 * 1024
 
 
 def vmem_working_set(bm: int, bn: int, bk: int, group: int,
                      act_bytes: int = 2, weight_elt_bytes: float = 0.5,
                      has_scales: bool = True,
-                     dequant_tile: bool = True) -> int:
+                     dequant_tile: bool = True, k: int = 0) -> int:
     """Bytes resident per grid step (double-buffered ins + fp32 acc).
 
     Defaults describe the fused W4A16 kernel (packed int4 weights at 0.5
     bytes/element, fp32 group scales, a dequantized tile feeding the MXU).
     Other weight stages override: dense GEMM has ``weight_elt_bytes=
     act_bytes`` and neither scales nor a dequant tile; per-channel INT8 has
-    ``weight_elt_bytes=1``.
+    ``weight_elt_bytes=1``. ``k`` (the full K) sizes the scale block the
+    template actually holds, which spans every K group; 0 counts only the
+    groups of one k block. A dequant tile also costs its int32 unpack and
+    fp32 dequant temporaries (8 bytes per element), which is what bounds
+    the tile on a v5e: compiling for one refuses (bk, bn) = (2560, 768)
+    and accepts (2560, 512).
     """
     x_blk = bm * bk * act_bytes
     w_blk = int(bk * bn * weight_elt_bytes)
-    s_blk = max(1, bk // max(group, 1)) * bn * 4 if has_scales else 0
-    deq = bk * bn * act_bytes if dequant_tile else 0
+    s_rows = max(1, (k or bk) // max(group, 1))
+    s_blk = s_rows * bn * 4 if has_scales else 0
+    deq = bk * bn * (act_bytes + 8) if dequant_tile else 0
     acc = bm * bn * 4
     return 2 * (x_blk + w_blk + s_blk) + deq + acc
 
@@ -79,32 +93,48 @@ def pad_dim(x: jax.Array, axis: int, multiple: int) -> jax.Array:
 
 
 def compiler_params(dimension_semantics):
-    """Best-effort TPU compiler params (ignored under interpret mode)."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
+    """Mosaic compiler params: the grid axes' dimension semantics."""
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
 
-        if hasattr(pltpu, "CompilerParams"):
-            return pltpu.CompilerParams(dimension_semantics=dimension_semantics)
-        return pltpu.TPUCompilerParams(dimension_semantics=dimension_semantics)
-    except Exception:  # pragma: no cover - older/newer API drift
-        return None
+
+@functools.lru_cache(maxsize=1)
+def target_spec() -> costmodel.TPUSpec:
+    """Peaks of the chip kernels are planned for: the local TPU's, looked
+    up by ``device_kind`` (an unknown kind raises), or a v5e's on a CPU
+    host, which plans for that chip rather than for itself."""
+    dev = jax.local_devices()[0]
+    if dev.platform == "cpu":
+        return costmodel.TPU_V5E
+    return costmodel.tpu_spec(dev.device_kind)
 
 
 def unpack_int4_block(packed) -> jax.Array:
-    """In-VMEM INT4→INT8 unpack of one packed weight block (no scaling).
+    """In-VMEM INT4 unpack of one packed weight block (no scaling).
 
     packed : (bk//2, bn) int8 ref/array — two nibbles per byte along K
-    returns: (bk, bn) int8 in [-8, 7]
+    returns: (bk, bn) int32 in [-8, 7]
 
-    Shift-based sign extension lowers to cheap VPU ops; the raw int8 tile
-    either feeds a float dequant (:func:`dequant_block`) or goes straight
-    into an int8×int8 MXU dot (the W4A8 contraction stage).
+    Shift-based sign extension lowers to cheap VPU ops. The shifts run on
+    int32 because the TPU vector unit has no 8-bit shifts. The raw tile
+    either feeds a float dequant (:func:`dequant_block`) or, cast to int8,
+    an int8×int8 MXU dot (the W4A8 contraction stage).
     """
-    b = packed[...]
-    lo = jnp.right_shift(jnp.left_shift(b, 4), 4)   # sign-extend low nibble
-    hi = jnp.right_shift(b, 4)                      # arithmetic → sign-extended
+    b = packed[...].astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(b, 28), 28)  # sign-extend low nibble
+    hi = jnp.right_shift(jnp.left_shift(b, 24), 28)  # ... and the high one
     k2, bn = b.shape
     return jnp.stack([lo, hi], axis=1).reshape(2 * k2, bn)
+
+
+def scale_rows(ref, row0, n: int) -> jax.Array:
+    """Rows ``[row0, row0 + n)`` of a VMEM scale block as an (n, bn) array.
+
+    Mosaic loads a dynamic run of rows only from an 8-aligned start, and a
+    k block's first group row is any multiple of ``bk // group``; one-row
+    loads are legal at every offset.
+    """
+    rows = [ref[pl.ds(row0 + i, 1), :] for i in range(n)]
+    return rows[0] if n == 1 else jnp.concatenate(rows, axis=0)
 
 
 def dequant_block(packed, scales, zeros, repeat: int, compute_dtype):

@@ -116,9 +116,9 @@ def _make_kernel(stage, *, P: int, window: int, n_stage: int,
         # duplicate, a rejected draft's stale tags) is masked so only the
         # in-flight segment supplies those positions. The null block's
         # tags are all -1, so unmapped table entries mask themselves out.
-        kpos = pp_ref[0][None, :]                         # (1, ps)
-        qe = qpos_ref[0, 0][:, None]                      # (QG, 1)
-        se = spos_ref[0, 0][:, None]
+        kpos = pp_ref[0]                                  # (1, ps)
+        qe = qpos_ref[0, 0]                               # (QG, 1)
+        se = spos_ref[0, 0]
         valid = (kpos >= 0) & (kpos <= qe) & (kpos < se)
         if window:
             valid &= kpos > qe - window
@@ -173,14 +173,14 @@ def _pooled_partials(qg, positions, start, pool, tables, *, window: int,
     P = T // S
 
     # host-side prep: q rows laid out (qt, tq, g); per-row positions and
-    # chunk starts expanded on the host so each kernel instance reads
-    # nothing but its own (1, 1, QG) block
+    # chunk starts expanded on the host into (QG, 1) columns, so each
+    # kernel instance reads nothing but its own block
     qk = qg.transpose(0, 2, 1, 3, 4).reshape(B, Hkv, QT, QG, D)
     qpos = jnp.broadcast_to(
         positions.reshape(B, QT, Tq, 1).astype(jnp.int32),
-        (B, QT, Tq, G)).reshape(B, QT, QG)
+        (B, QT, Tq, G)).reshape(B, QT, QG, 1)
     spos = jnp.broadcast_to(
-        start.reshape(B, 1, 1).astype(jnp.int32), (B, QT, QG))
+        start.reshape(B, 1, 1, 1).astype(jnp.int32), (B, QT, QG, 1))
     bt = jnp.where(tables < 0, 0, tables).astype(jnp.int32)   # NULL_BLOCK=0
 
     stage = kv_stage_for(pool, fmt)
@@ -196,26 +196,24 @@ def _pooled_partials(qg, positions, start, pool, tables, *, window: int,
     def page(bh, s, p, tbl):
         return tbl[slot(bh), s * P + p]
 
+    def row_block():
+        return pl.BlockSpec((1, 1, QG, 1),
+                            lambda bh, qt, s, p, tbl: (slot(bh), qt, 0, 0))
+
     in_specs = [
         pl.BlockSpec((1, 1, 1, QG, D),
                      lambda bh, qt, s, p, tbl:
                      (slot(bh), head(bh), qt, 0, 0)),
-        pl.BlockSpec((1, 1, QG),
-                     lambda bh, qt, s, p, tbl: (slot(bh), qt, 0)),
-        pl.BlockSpec((1, 1, QG),
-                     lambda bh, qt, s, p, tbl: (slot(bh), qt, 0)),
+        row_block(),
+        row_block(),
     ]
+    # stage operands are (nb, Hkv, ps, ·) pools, one (page, head) tile each
     for shape in stage.block_shapes(ps, D):
-        if len(shape) == 4:           # payload pool (nb, ps, Hkv, D)
-            in_specs.append(pl.BlockSpec(
-                shape, lambda bh, qt, s, p, tbl:
-                (page(bh, s, p, tbl), 0, head(bh), 0)))
-        else:                         # scale pool (nb, ps, Hkv)
-            in_specs.append(pl.BlockSpec(
-                shape, lambda bh, qt, s, p, tbl:
-                (page(bh, s, p, tbl), 0, head(bh))))
-    in_specs.append(pl.BlockSpec(                  # page_pos tags (nb, ps)
-        (1, ps), lambda bh, qt, s, p, tbl: (page(bh, s, p, tbl), 0)))
+        in_specs.append(pl.BlockSpec(
+            shape, lambda bh, qt, s, p, tbl:
+            (page(bh, s, p, tbl), head(bh), 0, 0)))
+    in_specs.append(pl.BlockSpec(            # page_pos tags as (nb, 1, ps)
+        (1, 1, ps), lambda bh, qt, s, p, tbl: (page(bh, s, p, tbl), 0, 0)))
 
     def part_spec(last):
         return pl.BlockSpec((1, 1, 1, 1, QG, last),
@@ -245,7 +243,7 @@ def _pooled_partials(qg, positions, start, pool, tables, *, window: int,
         compiler_params=common.compiler_params(
             ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(bt, qk, qpos, spos, *operands, pool.page_pos)
+    )(bt, qk, qpos, spos, *operands, pool.page_pos[:, None, :])
 
     def per_query(x):
         # (B, Hkv, QT, S, QG, ·) → (B, Hkv, C, S, G, ·): split QG = Tq·G
@@ -296,7 +294,7 @@ def fused_paged_attention(
     """
     interpret = common.resolve_interpret(interpret)
     B, Hq, D = q.shape
-    Hkv = pool.k_pool.shape[2]
+    Hkv = pool.k_pool.shape[1]
     G = Hq // Hkv
     # host-side prep, mirroring the gather path's dtype policy exactly:
     # q pre-scaled in fp32 then cast to the cache compute dtype

@@ -47,7 +47,6 @@ from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat  # noqa: F401  (registers vmap rules "xla" needs)
 from repro.core import costmodel
 from repro.core.quant import (
     DEFAULT_FORMAT,
@@ -58,7 +57,7 @@ from repro.core.quant import (
     w4a8_matmul_ref,
     w4a16_format_for,
 )
-from repro.kernels import ref
+from repro.kernels import common, ref
 from repro.kernels.w4a8_fused import w4a8_fused
 from repro.kernels.w4a16_decoupled import w4a16_decoupled
 from repro.kernels.w4a16_fused import w4a16_fused
@@ -89,6 +88,8 @@ class MatmulProblem:
     expert stacks); ``M`` is rows per GEMM. ``format`` is the registered
     :class:`~repro.core.quant.QuantFormat` name, so plans cache per-format
     and the planner can filter strategies on the formats they support.
+    ``spmd`` marks a GEMM inside one program that GSPMD partitions over
+    several devices, where a compiled Pallas kernel cannot run.
     """
 
     M: int
@@ -101,6 +102,7 @@ class MatmulProblem:
     backend: str = "cpu"
     batch: int = 1
     format: str = DEFAULT_FORMAT
+    spmd: bool = False
 
     @classmethod
     def from_operands(cls, x: jax.Array, qt: QuantizedTensor, *,
@@ -118,6 +120,7 @@ class MatmulProblem:
             backend=backend or jax.default_backend(),
             batch=batch,
             format=qt.format.name,
+            spmd=spmd_traced(),
         )
 
     @property
@@ -144,6 +147,23 @@ class MatmulProblem:
         return cls(**d)
 
 
+def spmd_traced() -> bool:
+    """Whether the current trace is one program that GSPMD partitions over
+    several devices: the ambient mesh has a non-manual axis of size > 1.
+    JAX refuses to lower a compiled Pallas (Mosaic) kernel into such a
+    program; inside ``shard_map`` the axes are manual and kernels run."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return any(mesh.shape[a] > 1 for a in mesh.axis_names
+               if a not in mesh.manual_axes)
+
+
+def pallas_lowers(backend: str, spmd: bool) -> bool:
+    """Whether a Pallas kernel can run in a problem's program: always in
+    interpret mode (it lowers to plain HLO, which partitions), but a
+    compiled TPU kernel only in a program of one device."""
+    return not (spmd and backend == "tpu")
+
+
 def _mesh_axis_size(mesh, name: str) -> int:
     """Axis size by name; 0 when absent (works on Mesh and spec-level fakes)."""
     try:
@@ -161,7 +181,8 @@ def shard_problem(problem: MatmulProblem, mesh, kind: str) -> MatmulProblem:
     sharded) divides N; ``kind="rep"`` leaves the weight whole. Data-parallel
     axes divide the activation rows M for every kind. A dim that the mesh
     doesn't divide stays global — mirroring ``runtime/sharding.py``, which
-    only shards divisible dims.
+    only shards divisible dims. A mesh of several devices marks the problem
+    ``spmd``: the sharded steps are GSPMD programs.
 
     Dispatch decisions (Split-K degree, tiles, memory round-trips) must be
     costed on THESE shapes: row-parallel sharding moves each rank's GEMM
@@ -186,7 +207,8 @@ def shard_problem(problem: MatmulProblem, mesh, kind: str) -> MatmulProblem:
             N //= model
         elif kind == "row" and K % model == 0:
             K //= model
-    return dataclasses.replace(problem, M=max(M, 1), N=N, K=K)
+    spmd = problem.spmd or math.prod(mesh.shape.values()) > 1
+    return dataclasses.replace(problem, M=max(M, 1), N=N, K=K, spmd=spmd)
 
 
 # ---------------------------------------------------------------------------
@@ -302,24 +324,18 @@ def strategies_for_format(format_name: str) -> Tuple[str, ...]:
 # Split-K heuristic (paper Fig. 2) and core counting
 # ---------------------------------------------------------------------------
 
-@functools.lru_cache(maxsize=1)
 def num_cores() -> int:
-    """Parallel-unit count for the occupancy heuristic: on TPU, the local
-    chips × 2 TensorCores (megacore); elsewhere the paper-model default of
-    8 — a CPU host is modeling the target chip, not itself."""
-    try:
-        dev = jax.local_devices()[0]
-        if dev.platform == "tpu":
-            return max(1, jax.local_device_count() * 2)
-    except Exception:  # pragma: no cover - no devices during docs builds
-        pass
-    return 8
+    """TensorCores one kernel's "parallel" grid axes spread over: those of
+    one chip of the planning target (``common.target_spec``). A kernel
+    runs on one chip, so other local chips never count — a v5e has one."""
+    return common.target_spec().cores_per_chip
 
 
 def choose_split_k(M: int, N: int, K: int, *, group_size: int = 128,
                    block_m: int = 128, block_n: int = 256) -> int:
     """Paper-informed Split-K heuristic: split when output tiles underfill
-    the chip and K is deep (K ≫ N — decode GEMMs)."""
+    the chip's cores (:func:`num_cores`) and K is deep (K ≫ N — decode
+    GEMMs)."""
     if group_size <= 0 or K % group_size:
         return 1          # K-slices could not stay group-aligned
     cores = num_cores()
@@ -349,20 +365,22 @@ def _pallas_factor(problem: MatmulProblem) -> float:
 
 
 def _cost_fused(problem: MatmulProblem, plan: KernelPlan) -> float:
-    return (costmodel.w4a16_time_tpu_fused(problem.M, problem.N, problem.K)
-            * problem.batch * _pallas_factor(problem))
+    return (costmodel.w4a16_time_tpu_fused(
+        problem.M, problem.N, problem.K, spec=common.target_spec())
+        * problem.batch * _pallas_factor(problem))
 
 
 def _cost_decoupled(problem: MatmulProblem, plan: KernelPlan) -> float:
     return (costmodel.w4a16_time_tpu_decoupled(
-        problem.M, problem.N, problem.K, split_k=max(plan.split_k, 1))
+        problem.M, problem.N, problem.K, split_k=max(plan.split_k, 1),
+        spec=common.target_spec())
         * problem.batch * _pallas_factor(problem))
 
 
 def _cost_xla(problem: MatmulProblem, plan: KernelPlan) -> float:
     """Dequant materialized once by XLA (int4 read + float write) + GEMM."""
     M, N, K = problem.M, problem.N, problem.K
-    spec = costmodel.TPU_V5E
+    spec = common.target_spec()
     t_deq = (0.5 * K * N + 2 * K * N) / spec.hbm_bw
     t_mm = max((2 * M * N * K) / spec.flops,
                (2 * M * K + 2 * K * N + 2 * M * N) / spec.hbm_bw)
@@ -379,7 +397,8 @@ def _cost_reference(problem: MatmulProblem, plan: KernelPlan) -> float:
 def _supports_pallas(problem: MatmulProblem) -> bool:
     # the kernels pad M and re-pick blocks, but K must be packable/grouped
     return (problem.group_size > 0 and problem.K % 2 == 0
-            and problem.K % problem.group_size == 0)
+            and problem.K % problem.group_size == 0
+            and pallas_lowers(problem.backend, problem.spmd))
 
 
 def _cost_w4a8(problem: MatmulProblem, plan: KernelPlan) -> float:
@@ -388,7 +407,7 @@ def _cost_w4a8(problem: MatmulProblem, plan: KernelPlan) -> float:
     fp32 group-accumulator the XLA einsum formulation materializes, which
     is exactly what the fused Pallas kernel avoids."""
     M, N, K = problem.M, problem.N, problem.K
-    spec = costmodel.TPU_V5E
+    spec = common.target_spec()
     g = max(problem.group_size, 1)
     bytes_moved = (M * K + 0.5 * K * N + 4.0 * K * N / g + 2 * M * N
                    + 8.0 * M * N * (K // g))        # write + read the acc
@@ -397,13 +416,15 @@ def _cost_w4a8(problem: MatmulProblem, plan: KernelPlan) -> float:
 
 
 def _cost_w8a16_fused(problem: MatmulProblem, plan: KernelPlan) -> float:
-    return (costmodel.w8a16_time_tpu_fused(problem.M, problem.N, problem.K)
-            * problem.batch * _pallas_factor(problem))
+    return (costmodel.w8a16_time_tpu_fused(
+        problem.M, problem.N, problem.K, spec=common.target_spec())
+        * problem.batch * _pallas_factor(problem))
 
 
 def _cost_w4a8_fused(problem: MatmulProblem, plan: KernelPlan) -> float:
     return (costmodel.w4a8_time_tpu_fused(
-        problem.M, problem.N, problem.K, group=problem.group_size)
+        problem.M, problem.N, problem.K, group=problem.group_size,
+        spec=common.target_spec())
         * problem.batch * _pallas_factor(problem))
 
 
@@ -477,7 +498,8 @@ def _run_decoupled(x2, qt, plan, *, interpret=None):
 def _supports_w8a16_pallas(problem: MatmulProblem) -> bool:
     # per-channel (or per-tensor) scales: one scale row spans all of K;
     # int8 rows have no packing constraint on K
-    return problem.group_size >= problem.K > 0
+    return (problem.group_size >= problem.K > 0
+            and pallas_lowers(problem.backend, problem.spmd))
 
 
 @register_strategy("w8a16_fused", cost=_cost_w8a16_fused,
@@ -846,7 +868,8 @@ class AttentionProblem:
     prefill (the chunk size) — and shifts the gather/fused tradeoff: the
     gather path re-materializes the whole window per step regardless of
     q_len, so its amortized cost collapses as q_len grows only for the
-    fused path."""
+    fused path. ``spmd`` marks a step that GSPMD partitions over several
+    devices, where the compiled fused kernel cannot run."""
     B: int
     Hq: int
     Hkv: int
@@ -859,6 +882,7 @@ class AttentionProblem:
     backend: str = "cpu"
     act_bytes: int = 2
     q_len: int = 1
+    spmd: bool = False
 
     @property
     def ctx(self) -> int:
@@ -939,7 +963,7 @@ def _cost_attn_ring(problem: AttentionProblem, plan: AttentionPlan) -> float:
     return costmodel.attn_decode_time_tpu(
         "ring", problem.B, problem.Hq, problem.Hkv, problem.D, problem.ctx,
         quantized=False, act_bytes=problem.act_bytes,
-        q_len=problem.q_len)
+        q_len=problem.q_len, spec=common.target_spec())
 
 
 def _cost_attn_gather(problem: AttentionProblem,
@@ -947,7 +971,8 @@ def _cost_attn_gather(problem: AttentionProblem,
     return costmodel.attn_decode_time_tpu(
         "gather", problem.B, problem.Hq, problem.Hkv, problem.D,
         problem.ctx, quantized=_attn_quantized(problem),
-        act_bytes=problem.act_bytes, q_len=problem.q_len)
+        act_bytes=problem.act_bytes, q_len=problem.q_len,
+        spec=common.target_spec())
 
 
 def _cost_attn_fused(problem: AttentionProblem,
@@ -956,7 +981,8 @@ def _cost_attn_fused(problem: AttentionProblem,
         "fused", problem.B, problem.Hq, problem.Hkv, problem.D,
         problem.ctx, quantized=_attn_quantized(problem),
         act_bytes=problem.act_bytes, q_len=problem.q_len,
-        kv_partitions=plan.kv_partitions) * _attn_pallas_factor(problem)
+        kv_partitions=plan.kv_partitions, spec=common.target_spec()
+    ) * _attn_pallas_factor(problem)
 
 
 register_attn_path("ring", cost=_cost_attn_ring,
@@ -964,7 +990,8 @@ register_attn_path("ring", cost=_cost_attn_ring,
 register_attn_path("gather", cost=_cost_attn_gather,
                    supports=lambda p: p.paged)
 register_attn_path("fused", cost=_cost_attn_fused,
-                   supports=lambda p: p.paged)
+                   supports=lambda p: p.paged
+                   and pallas_lowers(p.backend, p.spmd))
 
 
 def _attn_plan_for(problem: AttentionProblem, name: str) -> AttentionPlan:
@@ -1009,7 +1036,8 @@ def plan_attention(problem: AttentionProblem, *,
                         if e.supports(problem)]
             raise ValueError(
                 f"attention path {path!r} does not support this problem "
-                f"(paged={problem.paged}); paths that do: {eligible}")
+                f"(paged={problem.paged}, backend={problem.backend!r}, "
+                f"spmd={problem.spmd}); paths that do: {eligible}")
         return _attn_plan_for(problem, path)
 
     best: Optional[Tuple[float, int, AttentionPlan]] = None

@@ -120,7 +120,7 @@ def choose_blocks(
         return common.vmem_working_set(
             bm, bn_, bk_, group_size or K, act_bytes=act_bytes,
             weight_elt_bytes=weight_elt_bytes, has_scales=has_scales,
-            dequant_tile=dequant_tile)
+            dequant_tile=dequant_tile, k=K)
 
     while working_set(bn, bk) > vmem_budget and bk > 1:
         bk = shrink(bk)
@@ -156,17 +156,15 @@ class DenseWeight:
     def layout(self, bc: BlockConfig) -> List[Tuple[Tuple[int, int], RowFn]]:
         return [((bc.bk, bc.bn), lambda kk: kk)]
 
-    def produce(self, refs: Sequence, bc: BlockConfig, compute_dtype):
+    def produce(self, refs: Sequence, bc: BlockConfig, compute_dtype, kk):
         (w_ref,) = refs
         return w_ref[...]
 
 
-def _group_layout(bc: BlockConfig) -> Tuple[int, int, RowFn]:
-    """(repeat, scale-rows-per-block, scale row fn) for grouped scales."""
+def _group_layout(bc: BlockConfig) -> Tuple[int, int]:
+    """(repeat, scale rows per k block) for grouped scales."""
     g = bc.group_size
-    repeat = min(bc.bk, g)
-    spb = max(1, bc.bk // g)
-    return repeat, spb, lambda kk: (kk * bc.bk) // g // spb
+    return min(bc.bk, g), max(1, bc.bk // g)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -186,18 +184,28 @@ class GroupedInt4Dequant:
         return ops
 
     def layout(self, bc: BlockConfig) -> List[Tuple[Tuple[int, int], RowFn]]:
-        _, spb, sfn = _group_layout(bc)
+        # the scale block spans the whole K/g axis (a few KiB): a (bk/g, bn)
+        # block would break the TPU's 8-row tiling whenever bk/g < 8, so
+        # the k block's rows are sliced in-kernel (_group_rows) instead
+        rows = self.scales.shape[0]
         specs = [((bc.bk // 2, bc.bn), lambda kk: kk),
-                 ((spb, bc.bn), sfn)]
+                 ((rows, bc.bn), lambda kk: 0)]
         if self.zeros is not None:
-            specs.append(((spb, bc.bn), sfn))
+            specs.append(((rows, bc.bn), lambda kk: 0))
         return specs
 
-    def produce(self, refs: Sequence, bc: BlockConfig, compute_dtype):
+    def produce(self, refs: Sequence, bc: BlockConfig, compute_dtype, kk):
         p_ref, s_ref, *z = refs
-        repeat, _, _ = _group_layout(bc)
+        repeat, _ = _group_layout(bc)
         return common.dequant_block(
-            p_ref, s_ref, z[0] if z else None, repeat, compute_dtype)
+            p_ref, _group_rows(s_ref, bc, kk),
+            _group_rows(z[0], bc, kk) if z else None, repeat, compute_dtype)
+
+
+def _group_rows(ref, bc: BlockConfig, kk):
+    """The scale rows of global k block ``kk`` from a whole-K scale block."""
+    _, spb = _group_layout(bc)
+    return common.scale_rows(ref, (kk * bc.bk) // bc.group_size, spb)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -223,7 +231,7 @@ class ChannelInt8Dequant:
             specs.append(((1, bc.bn), lambda kk: 0))
         return specs
 
-    def produce(self, refs: Sequence, bc: BlockConfig, compute_dtype):
+    def produce(self, refs: Sequence, bc: BlockConfig, compute_dtype, kk):
         r_ref, s_ref, *z = refs
         return common.dequant_channel_block(
             r_ref, s_ref, z[0] if z else None, compute_dtype)
@@ -249,10 +257,11 @@ class GroupedInt4Raw:
     operands = GroupedInt4Dequant.operands
     layout = GroupedInt4Dequant.layout
 
-    def produce(self, refs: Sequence, bc: BlockConfig, compute_dtype):
+    def produce(self, refs: Sequence, bc: BlockConfig, compute_dtype, kk):
         p_ref, s_ref, *z = refs
-        return (common.unpack_int4_block(p_ref), s_ref,
-                z[0] if z else None)
+        return (common.unpack_int4_block(p_ref).astype(jnp.int8),
+                _group_rows(s_ref, bc, kk),
+                _group_rows(z[0], bc, kk) if z else None)
 
 
 # ---------------------------------------------------------------------------
@@ -266,11 +275,11 @@ class GroupedInt4Raw:
 # ``kv8_channel`` (the same AIV dequant role the GEMM weight stages play,
 # fused into the consumer instead of round-tripping through HBM).
 #
-# ``block_shapes`` distinguishes operand kinds by rank: 4-d blocks
-# ``(1, ps, 1, D)`` are payload pools indexed ``(page, 0, head, 0)``;
-# 3-d blocks ``(1, ps, 1)`` are scale pools indexed ``(page, 0, head)``.
-# The emitter (kernels/paged_attention.py) turns those into block-table
-# index maps over the scalar-prefetched tables.
+# Every operand is blocked as ``(1, 1, page_size, ·)`` over a
+# ``(num_blocks, Hkv, page_size, ·)`` array and indexed ``(page, head, 0,
+# 0)``: the last two block dims are whole axes, as the TPU's (8, 128)
+# tiling rule requires. The emitter (kernels/paged_attention.py) turns that
+# into block-table index maps over the scalar-prefetched tables.
 # ---------------------------------------------------------------------------
 
 
@@ -279,19 +288,19 @@ class DensePages:
     """Identity KV stage: pool pages already hold the cache dtype
     (``kv_fp16`` — no scales, no dequant)."""
 
-    k_pool: jax.Array                 # (num_blocks, ps, Hkv, D)
+    k_pool: jax.Array                 # (num_blocks, Hkv, ps, D)
     v_pool: jax.Array
 
     def operands(self) -> List[jax.Array]:
         return [self.k_pool, self.v_pool]
 
     def block_shapes(self, ps: int, D: int) -> List[Tuple[int, ...]]:
-        return [(1, ps, 1, D), (1, ps, 1, D)]
+        return [(1, 1, ps, D), (1, 1, ps, D)]
 
     def produce(self, refs: Sequence, compute_dtype):
         k_ref, v_ref = refs
-        return (k_ref[0, :, 0, :].astype(compute_dtype),
-                v_ref[0, :, 0, :].astype(compute_dtype))
+        return (k_ref[0, 0].astype(compute_dtype),
+                v_ref[0, 0].astype(compute_dtype))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -304,24 +313,25 @@ class Int8ChannelPages:
     dequantized window to HBM before attention reads it back).
     """
 
-    k_pool: jax.Array                 # (num_blocks, ps, Hkv, D) int8
+    k_pool: jax.Array                 # (num_blocks, Hkv, ps, D) int8
     v_pool: jax.Array
-    k_scale: jax.Array                # (num_blocks, ps, Hkv) fp32
+    k_scale: jax.Array                # (num_blocks, Hkv, ps) fp32
     v_scale: jax.Array
 
     def operands(self) -> List[jax.Array]:
-        return [self.k_pool, self.v_pool, self.k_scale, self.v_scale]
+        # scales get a unit lane axis: a (ps, 1) column per (page, head)
+        return [self.k_pool, self.v_pool,
+                self.k_scale[..., None], self.v_scale[..., None]]
 
     def block_shapes(self, ps: int, D: int) -> List[Tuple[int, ...]]:
-        return [(1, ps, 1, D), (1, ps, 1, D), (1, ps, 1), (1, ps, 1)]
+        return [(1, 1, ps, D), (1, 1, ps, D), (1, 1, ps, 1), (1, 1, ps, 1)]
 
     def produce(self, refs: Sequence, compute_dtype):
         k_ref, v_ref, ks_ref, vs_ref = refs
 
         def deq(p_ref, s_ref):
-            q = p_ref[0, :, 0, :].astype(jnp.float32)       # (ps, D)
-            s = s_ref[0, :, 0].astype(jnp.float32)          # (ps,)
-            return (q * s[:, None]).astype(compute_dtype)
+            q = p_ref[0, 0].astype(jnp.float32)             # (ps, D)
+            return (q * s_ref[0, 0]).astype(compute_dtype)  # × (ps, 1)
 
         return deq(k_ref, ks_ref), deq(v_ref, vs_ref)
 
@@ -350,7 +360,7 @@ class Int8GroupContraction:
 
     def step(self, x_tile, w_prod, acc_ref, bc: BlockConfig) -> None:
         wq, s_ref, z_ref = w_prod
-        repeat, spb, _ = _group_layout(bc)
+        repeat, spb = _group_layout(bc)
         for i in range(spb):                      # static unroll over groups
             xs = x_tile[:, i * repeat:(i + 1) * repeat]
             ws = wq[i * repeat:(i + 1) * repeat, :]
@@ -375,12 +385,14 @@ def _make_kernel(weight_stage, contraction, bc: BlockConfig, *,
         w_refs = rest[:n_weight_refs]
         o_ref, acc_ref = rest[n_weight_refs:]
         k = pl.program_id(k_axis)
+        # global k block: Split-K slice s covers blocks s*nk .. s*nk+nk-1
+        kk = pl.program_id(0) * bc.nk + k if partial_out else k
 
         @pl.when(k == 0)
         def _init():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        w_tile = weight_stage.produce(w_refs, bc, compute_dtype)
+        w_tile = weight_stage.produce(w_refs, bc, compute_dtype, kk)
         contraction.step(x_ref[...], w_tile, acc_ref, bc)
 
         @pl.when(k == pl.num_programs(k_axis) - 1)

@@ -31,16 +31,21 @@ from repro.kernels import common, template
 # Phase 1: dequant (vector-core role)
 # ---------------------------------------------------------------------------
 
-def _make_dequant_kernel(repeat: int, has_zeros: bool):
+def _make_dequant_kernel(bk: int, g: int, has_zeros: bool):
+    spb = max(1, bk // g)
+
     def kernel(p_ref, s_ref, *rest):
         if has_zeros:
             z_ref, o_ref = rest
         else:
             z_ref = None
             (o_ref,) = rest
+        # the scale block spans all K/g rows; take this k block's groups
+        row0 = (pl.program_id(0) * bk) // g
         o_ref[...] = common.dequant_block(
-            p_ref, s_ref, z_ref, repeat, o_ref.dtype
-        )
+            p_ref, common.scale_rows(s_ref, row0, spb),
+            None if z_ref is None else common.scale_rows(z_ref, row0, spb),
+            min(bk, g), o_ref.dtype)
 
     return kernel
 
@@ -66,21 +71,19 @@ def dequant_w4(
     bk = common.pick_block(K, block_k)
     while bk > 1 and not (bk % g == 0 or g % bk == 0):
         bk = common.largest_divisor(K, bk - 1)
-    repeat = min(bk, g)
-    spb = max(1, bk // g)
     has_zeros = qt.zeros is not None
 
     in_specs = [
         pl.BlockSpec((bk // 2, bn), lambda k, n: (k, n)),
-        pl.BlockSpec((spb, bn), lambda k, n: ((k * bk) // g // spb, n)),
+        pl.BlockSpec((K // g, bn), lambda k, n: (0, n)),
     ]
     operands = [qt.packed, qt.scales]
     if has_zeros:
-        in_specs.append(pl.BlockSpec((spb, bn), in_specs[1].index_map))
+        in_specs.append(pl.BlockSpec((K // g, bn), in_specs[1].index_map))
         operands.append(qt.zeros)
 
     return pl.pallas_call(
-        _make_dequant_kernel(repeat, has_zeros),
+        _make_dequant_kernel(bk, g, has_zeros),
         grid=(K // bk, N // bn),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bk, bn), lambda k, n: (k, n)),

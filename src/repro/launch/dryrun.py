@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
-from repro.core import compat
 from repro.configs import SHAPES, input_specs, skip_reason, cache_len_for
 from repro.launch.mesh import make_production_mesh
 from repro.launch.presets import settings_for
@@ -304,9 +303,9 @@ def _abstract_opt_state(params_abs, opt_cfg):
 
 def _serve_cfg(cfg):
     """Serving config: W4A16 via the XLA-fusable dequant+dot formulation —
-    the Pallas fused kernel is dispatched per-shard (shard_map) on real TPU;
-    for SPMD lowering the HLO-level formulation partitions identically.
-    See DESIGN.md §Hardware adaptation."""
+    GSPMD cannot partition a compiled Pallas kernel, so a step sharded over
+    the production mesh runs the HLO formulation (``planning.pallas_lowers``
+    makes the same choice for the serving engine on several chips)."""
     return dataclasses.replace(cfg, w4a16_strategy="xla",
                                moe_manual_dispatch=True)
 
@@ -341,7 +340,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         opt_abs = _abstract_opt_state(params_abs, opt_cfg)
         inputs_abs = {"batch": specs["batch"],
                       "step": jax.ShapeDtypeStruct((), jnp.int32)}
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn = rsteps.jit_train_step(cfg, mesh, settings, params_abs,
                                        inputs_abs, opt_cfg)
             lowered = fn.lower(params_abs, opt_abs, inputs_abs)
@@ -354,14 +353,14 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             lambda p: T.quantize_params(p, scfg), params_abs)
 
     if shape.kind == "prefill":
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn = rsteps.jit_prefill_step(
                 scfg, mesh, cache_len_for(scfg, shape), params_abs, specs,
                 fsdp_serve=settings.fsdp_serve)
             lowered = fn.lower(params_abs, specs)
         return lowered, {"mesh": mesh, "kind": "prefill"}
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = rsteps.jit_serve_step(scfg, mesh, params_abs, specs,
                                    fsdp_serve=settings.fsdp_serve)
         lowered = fn.lower(params_abs, specs)

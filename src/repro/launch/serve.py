@@ -42,7 +42,7 @@ import jax.numpy as jnp
 from repro import configs
 from repro.core import quant
 from repro.kernels import planning
-from repro.launch import mesh as launch_mesh
+from repro.launch import compile_cache, mesh as launch_mesh
 from repro.launch.presets import serve_settings_for
 from repro.models import transformer as T
 from repro.runtime import speculative
@@ -225,6 +225,7 @@ def main(argv=None):
     ap.add_argument("--verbose", action="store_true",
                     help="per-step engine log lines")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.plan_cache and os.path.exists(args.plan_cache):
         n = planning.load_plan_cache(args.plan_cache, tolerant=True)

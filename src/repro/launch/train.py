@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import argparse
 import os
+import tempfile
 import time
 
 import jax
@@ -18,6 +19,7 @@ import jax.numpy as jnp
 from repro import configs
 from repro.data import SyntheticTokenStream
 from repro.kernels import planning
+from repro.launch import compile_cache
 from repro.launch.presets import settings_for
 from repro.models import transformer as T
 from repro.optim import AdamWConfig, adamw_init
@@ -43,7 +45,8 @@ def main(argv=None):
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(tempfile.gettempdir(), "repro_ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=10)
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--plan-cache", default=None,
@@ -55,6 +58,7 @@ def main(argv=None):
                          "serving-GEMM planning pass (any registered "
                          "QuantFormat name; default: config quant_format)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.plan_cache and os.path.exists(args.plan_cache):
         if planning.load_plan_cache(args.plan_cache, tolerant=True) < 0:

@@ -13,7 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat, quant
+from repro.core import quant
 from repro.core.quant import QuantizedTensor, quantize
 from repro.kernels import planning
 
@@ -30,8 +30,8 @@ def shard_hint(x: jax.Array, kind: str) -> jax.Array:
     kinds: "bsd" (B,S,d) · "bshd" (B,S,H,D) · "bd" (B,d) · "bhd" (B,H,D)
          · "ecd" (E,cap,d) MoE dispatch buffers — capacity dim over DP axes
     """
-    mesh = compat.get_abstract_mesh()
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return x
     names = mesh.axis_names
     dp = tuple(a for a in ("pod", "data") if a in names)

@@ -24,7 +24,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core import compat
 from repro.kernels import planning
 from repro.models import layers
 
@@ -64,8 +63,8 @@ def _expert_matmul(w, x, cfg):
 
 def _dp_axes(T: int):
     """DP axes of the ambient mesh that divide T (empty outside set_mesh)."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return (), None
     axes = []
     n = 1
@@ -151,7 +150,7 @@ def moe_ffn(p, x: jax.Array, *, num_experts: int, top_k: int,
                 capacity_factor=capacity_factor, cfg=cfg)
             return y, jax.lax.pmean(a, dp)
 
-        yt, aux = compat.shard_map(
+        yt, aux = jax.shard_map(
             local, mesh=mesh, axis_names=set(dp),
             in_specs=(P(), P(dp, None)),
             out_specs=(P(dp, None), P()),

@@ -54,7 +54,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.shapes import serve_cache_len, serve_num_pages
-from repro.core import compat
 from repro.core.quant import (
     DEFAULT_KV_FORMAT, QuantizedTensor, get_kv_format,
 )
@@ -335,16 +334,19 @@ class ServingEngine:
 
         # decode-attention path: a costed plan decision, same shape as the
         # matmul planner — "auto" ranks ring/gather/fused on the engine's
-        # true decode problem (gather on CPU hosts, fused on TPU for long
-        # contexts); a forced path is validated against the engine mode
-        # (e.g. "fused" without the paged cache is refused loudly)
+        # true decode problem (gather on CPU hosts and on a mesh of several
+        # TPU chips, whose GSPMD steps cannot hold a compiled kernel; fused
+        # on one TPU chip for long contexts); a forced path is validated
+        # against the engine mode (e.g. "fused" without the paged cache is
+        # refused loudly)
         attn_problem = planning.AttentionProblem(
             B=self.max_batch, Hq=cfg.num_heads, Hkv=cfg.num_kv_heads,
             D=cfg.head_dim, cache_len=self.cache_len,
             page_size=self.page_size, window=cfg.sliding_window,
             kv_format=self.kv_format, paged=self.paged,
             backend=jax.default_backend(),
-            act_bytes=jnp.dtype(cfg.dtype).itemsize)
+            act_bytes=jnp.dtype(cfg.dtype).itemsize,
+            spmd=mesh is not None and mesh.size > 1)
         forced_path = None if attn_path == "auto" else attn_path
         attn_plan = planning.plan_attention(attn_problem, path=forced_path)
         self.attn_path = attn_plan.path
@@ -448,7 +450,7 @@ class ServingEngine:
     # -- compiled steps ----------------------------------------------------
 
     def _ctx(self):
-        return compat.set_mesh(self.mesh) if self.mesh is not None \
+        return jax.set_mesh(self.mesh) if self.mesh is not None \
             else contextlib.nullcontext()
 
     def _prefill_inputs(self, req: Request):

@@ -6,7 +6,7 @@ tensor whose HBM footprint and traffic decide throughput. This module
 replaces the per-slot contiguous ring caches with a **block pool**:
 
   device side  — :class:`PagedKVCache`: ``k_pool``/``v_pool`` of
-                 ``num_blocks × page_size × Hkv × D`` (per layer; the model
+                 ``num_blocks × Hkv × page_size × D`` (per layer; the model
                  stacks an L axis on top) plus per-slot ``page_pos`` tags
                  and optional ``kv8_channel`` scales. Gather/scatter run
                  through per-slot **block tables** ``(B, pages_per_slot)``.
@@ -60,11 +60,16 @@ NULL_BLOCK = 0
 class PagedKVCache(NamedTuple):
     """Block-pool KV cache (one layer; the model stacks L in front).
 
-    ``k_pool``/``v_pool``: (num_blocks, page_size, Hkv, D) — cache dtype for
-    ``kv_fp16``, int8 for ``kv8_channel`` with per-(token, head) fp32
-    scales in ``k_scale``/``v_scale`` (num_blocks, page_size, Hkv).
+    ``k_pool``/``v_pool``: (num_blocks, Hkv, page_size, D) — cache dtype
+    for ``kv_fp16``, int8 for ``kv8_channel`` with per-(token, head) fp32
+    scales in ``k_scale``/``v_scale`` (num_blocks, Hkv, page_size).
     ``page_pos``: (num_blocks, page_size) int32 absolute positions, -1 empty
     — the same validity tags ``attention.KVCache`` masks on.
+
+    Heads sit outside the page's token axis so that one (block, head) tile
+    is a whole ``(page_size, D)`` matrix: the fused kernel streams it
+    through VMEM as a block whose last two dims are full axes, which the
+    TPU's (8, 128) tiling rule requires.
     """
 
     k_pool: jax.Array
@@ -87,15 +92,18 @@ def init_pool(num_blocks: int, page_size: int, num_kv_heads: int,
               ) -> PagedKVCache:
     """Fresh pool; block 0 is the null block (never allocated)."""
     fmt = get_kv_format(kv_format)
-    shape = (num_blocks, page_size, num_kv_heads, head_dim)
+    shape = (num_blocks, num_kv_heads, page_size, head_dim)
     payload_dtype = jnp.int8 if fmt.quantized else dtype
-    scale = (jnp.zeros(shape[:-1], jnp.float32) if fmt.quantized else None)
+
+    def scale():
+        return jnp.zeros(shape[:-1], jnp.float32) if fmt.quantized else None
+
     return PagedKVCache(
         k_pool=jnp.zeros(shape, payload_dtype),
         v_pool=jnp.zeros(shape, payload_dtype),
         page_pos=jnp.full((num_blocks, page_size), -1, jnp.int32),
-        k_scale=scale,
-        v_scale=None if scale is None else jnp.zeros(shape[:-1], jnp.float32),
+        k_scale=scale(),
+        v_scale=scale(),
     )
 
 
@@ -110,16 +118,6 @@ def pages_per_slot(cache_len: int, page_size: int) -> int:
 # ---------------------------------------------------------------------------
 # device ops: gather / scatter through block tables
 # ---------------------------------------------------------------------------
-
-def _flat(pool_leaf: jax.Array) -> jax.Array:
-    """(nb, ps, ...) → (nb*ps, ...) flat token-slot view."""
-    nb, ps = pool_leaf.shape[:2]
-    return pool_leaf.reshape(nb * ps, *pool_leaf.shape[2:])
-
-
-def _unflat(flat_leaf: jax.Array, nb: int, ps: int) -> jax.Array:
-    return flat_leaf.reshape(nb, ps, *flat_leaf.shape[1:])
-
 
 def gather_window(pool: PagedKVCache, tables: jax.Array, *,
                   fmt: KVFormat, out_dtype,
@@ -147,9 +145,11 @@ def gather_window(pool: PagedKVCache, tables: jax.Array, *,
     B, T = bt.shape
     ps = pool.page_size
 
-    def take(leaf):                                        # (nb, ps, ...) →
-        g = jnp.take(leaf, bt.reshape(-1), axis=0)         # (B*T, ps, ...)
-        return g.reshape(B, T * ps, *leaf.shape[2:])
+    def take(leaf):               # (nb, Hkv, ps, ...) → (B, T*ps, Hkv, ...)
+        g = jnp.swapaxes(jnp.take(leaf, bt.reshape(-1), axis=0), 1, 2)
+        return g.reshape(B, T * ps, *g.shape[2:])
+
+    pos = jnp.take(pool.page_pos, bt.reshape(-1), axis=0).reshape(B, T * ps)
 
     if not fmt.quantized:
         # passthrough formats store the cache dtype directly: no dequant
@@ -159,38 +159,38 @@ def gather_window(pool: PagedKVCache, tables: jax.Array, *,
         if k.dtype != jnp.dtype(out_dtype):
             k = k.astype(out_dtype)
             v = v.astype(out_dtype)
-        return attention.KVCache(k=k, v=v, pos=take(pool.page_pos))
+        return attention.KVCache(k=k, v=v, pos=pos)
     k = kv_dequantize(take(pool.k_pool),
                       None if pool.k_scale is None else take(pool.k_scale),
                       fmt, out_dtype)
     v = kv_dequantize(take(pool.v_pool),
                       None if pool.v_scale is None else take(pool.v_scale),
                       fmt, out_dtype)
-    return attention.KVCache(k=k, v=v, pos=take(pool.page_pos))
+    return attention.KVCache(k=k, v=v, pos=pos)
 
 
 def _scatter(pool: PagedKVCache, flat_idx: jax.Array, k_new, v_new,
              pos_tag: jax.Array, fmt: KVFormat) -> PagedKVCache:
     """Write token vectors at flat pool slots (shared scatter core).
 
-    flat_idx/pos_tag: (n,); k_new/v_new: (n, Hkv, D) in compute dtype.
+    flat_idx/pos_tag: (n,) with ``flat = block * page_size + offset``;
+    k_new/v_new: (n, Hkv, D) in compute dtype.
     """
-    nb, ps = pool.num_blocks, pool.page_size
+    bid, off = jnp.divmod(flat_idx, pool.page_size)
     kq, ks = kv_quantize(k_new, fmt)
     vq, vs = kv_quantize(v_new, fmt)
-    kq = kq.astype(pool.k_pool.dtype)
-    vq = vq.astype(pool.v_pool.dtype)
-    out = PagedKVCache(
-        k_pool=_unflat(_flat(pool.k_pool).at[flat_idx].set(kq), nb, ps),
-        v_pool=_unflat(_flat(pool.v_pool).at[flat_idx].set(vq), nb, ps),
-        page_pos=_unflat(_flat(pool.page_pos).at[flat_idx].set(pos_tag),
-                         nb, ps),
-        k_scale=pool.k_scale if ks is None else _unflat(
-            _flat(pool.k_scale).at[flat_idx].set(ks), nb, ps),
-        v_scale=pool.v_scale if vs is None else _unflat(
-            _flat(pool.v_scale).at[flat_idx].set(vs), nb, ps),
+
+    def put(leaf, rows):          # leaf[bid, :, off] is (n, Hkv, ...)
+        return leaf if rows is None else \
+            leaf.at[bid, :, off].set(rows.astype(leaf.dtype))
+
+    return PagedKVCache(
+        k_pool=put(pool.k_pool, kq),
+        v_pool=put(pool.v_pool, vq),
+        page_pos=pool.page_pos.at[bid, off].set(pos_tag),
+        k_scale=put(pool.k_scale, ks),
+        v_scale=put(pool.v_scale, vs),
     )
-    return out
 
 
 def _write_target(tables: jax.Array, offset: jax.Array, page_size: int,

@@ -191,10 +191,10 @@ def decode_state_shardings(state, cfg, mesh):
         spec = [None] * leaf.ndim
         if any(n in ("k_pool", "v_pool", "k_scale", "v_scale", "page_pos")
                for n in names):
-            # (L, nb, ps, Hkv, D) / (L, nb, ps, Hkv) / (L, nb, ps):
+            # (L, nb, Hkv, ps, D) / (L, nb, Hkv, ps) / (L, nb, ps):
             # replicate pages over DP; shard the head dim over model
-            if leaf.ndim >= 4 and _divisible(leaf.shape[3], model):
-                spec[3] = "model"
+            if leaf.ndim >= 4 and _divisible(leaf.shape[2], model):
+                spec[2] = "model"
             return NamedSharding(mesh, P(*spec))
         # layer-stacked leaves: axis0=L, axis1=B, then shape-specific
         if leaf.ndim >= 2:

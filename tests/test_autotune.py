@@ -21,26 +21,26 @@ from repro.kernels.w4a16_fused import w4a16_fused
                                [2048, 4096, 16384]))
 def test_autotune_fits_vmem_and_divides(M, N, K):
     bm, bn, bk, s = autotune_w4a16(M, N, K, group=128)
-    assert vmem_working_set(bm, bn, bk, 128) <= VMEM_BUDGET
+    assert vmem_working_set(bm, bn, bk, 128, k=K) <= VMEM_BUDGET
     assert N % bn == 0 and (K // s) % bk == 0 and K % s == 0
     assert bk % 128 == 0 or 128 % bk == 0
 
 
-def test_autotune_split_k_regimes():
+def test_autotune_split_k_regimes(monkeypatch):
     """TPU-adapted Split-K: with int4 weights the HBM term dominates every
-    realistic shape and is invariant in S, while a chip has only 2 parallel
-    units (megacore), not
-    Ascend's 32 cores, so intra-chip Split-K only pays when a single
-    output tile leaves a core idle on a compute-bound GEMM; memory-bound
-    decode GEMMs are traffic-invariant in S (the paper's occupancy win
-    moves to mesh-level K-sharding — see DESIGN.md)."""
+    realistic shape and is invariant in S, while a v5e chip has one
+    TensorCore, not Ascend's 32 cores, so intra-chip Split-K never fills
+    an idle core; memory-bound decode GEMMs are traffic-invariant in S
+    (the paper's occupancy win moves to mesh-level K-sharding)."""
     for (M, N, K) in [(128, 128, 65536), (1, 1024, 16384),
                       (2048, 8192, 4096)]:
         _, _, _, s = autotune_w4a16(M, N, K)
         assert s == 1, (M, N, K, s)
     # the Ascend-faithful heuristic (32-core occupancy) DOES split there:
-    from repro.kernels.ops import choose_split_k
-    assert choose_split_k(1, 1024, 16384) >= 2
+    from repro.core.costmodel import ASCEND
+    from repro.kernels import planning
+    monkeypatch.setattr(planning, "num_cores", lambda: ASCEND.num_cores)
+    assert planning.choose_split_k(1, 1024, 16384) >= 2
 
 
 def test_autotuned_blocks_run_correctly():

@@ -4,6 +4,7 @@ These are the EXPERIMENTS.md validation gates: if the model drifts away from
 the paper's published numbers, these tests fail.
 """
 import numpy as np
+import pytest
 
 from repro.configs import PAPER_BATCH_SIZES, PAPER_GEMM_SHAPES
 from repro.core import costmodel as cm
@@ -68,3 +69,10 @@ def test_tpu_fused_removes_roundtrip_penalty():
 def test_best_splitk_prefers_deep_k():
     assert cm.best_split_k_ascend(1, 1024, 16384) >= 2
     assert cm.best_split_k_ascend(2048, 8192, 1024) == 1
+
+
+def test_peak_table_keyed_by_device_kind_raises_on_unknown():
+    assert cm.tpu_spec("TPU v5 lite") is cm.TPU_V5E
+    assert cm.TPU_V5E.flops == 197e12 and cm.TPU_V5E.hbm_bw == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        cm.tpu_spec("TPU v9 imaginary")

@@ -23,7 +23,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
-from repro.core.compat import set_mesh, shard_map
 from repro.models import transformer as T
 from repro.optim import AdamWConfig, adamw_init
 from repro.runtime import steps as rsteps
@@ -45,8 +44,9 @@ inputs = {"batch": {"tokens": toks, "labels": toks},
 single = jax.jit(rsteps.make_train_step(cfg, opt_cfg, settings))
 p1, o1, m1 = single(params, opt, inputs)
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
-with set_mesh(mesh):
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
+with jax.set_mesh(mesh):
     fn = rsteps.jit_train_step(
         cfg, mesh, settings,
         jax.eval_shape(lambda: params),
@@ -74,11 +74,11 @@ def per_shard(x, packed, scales):
     q = QuantizedTensor(packed, scales, None, 64, jnp.dtype(jnp.float32))
     return w4a16_fused(x, q, interpret=True)
 
-tp = shard_map(
+tp = jax.shard_map(
     per_shard, mesh=mesh,
     in_specs=(P(None, None), P(None, "model"), P(None, "model")),
     out_specs=P(None, "model"), check_vma=False)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     y = tp(x, qt.packed, qt.scales)
 want = ref.w4a16_ref(x, qt)
 out["tp_w4a16_err"] = float(jnp.abs(y - want).max())

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.configs import SHAPES, input_specs
-from repro.core.compat import set_mesh
 from repro.launch.mesh import make_production_mesh, degraded_mesh
 from repro.launch.presets import settings_for
 from repro.models import transformer as T
@@ -53,7 +52,7 @@ for name, mesh in [("full", make_production_mesh()),
         specs = input_specs(cfg, shape2)
         inputs_abs = {"batch": specs["batch"],
                       "step": jax.ShapeDtypeStruct((), jnp.int32)}
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn = rsteps.jit_train_step(cfg, mesh, settings, params_abs,
                                    inputs_abs, opt_cfg)
         compiled = fn.lower(params_abs, opt_abs, inputs_abs).compile()

@@ -149,13 +149,18 @@ def test_shard_problem_local_shapes():
     assert local.M == 2
 
 
-def test_plan_for_params_drops_ambiguous_square_keys():
+def test_plan_for_params_drops_ambiguous_square_keys(monkeypatch):
     """wq (col) and wo (row) of a square attention projection share the
     global layer_key: when their shard-local plans disagree the key must be
     dropped (global-planner fallback) — never hand one layer the other's
-    wrong-shape plan."""
+    wrong-shape plan. Local plans differ only in the Split-K degree of a
+    Pallas kernel, so the kernels are taken to run per shard on a chip
+    with cores to fill (a GSPMD step on TPU chips plans XLA GEMMs, and a
+    v5e has one TensorCore: neither ever disagrees)."""
     from repro.core.quant import quantize
 
+    monkeypatch.setattr(planning, "pallas_lowers", lambda backend, spmd: True)
+    monkeypatch.setattr(planning, "num_cores", lambda: 8)
     w = jax.random.normal(KEY, (1024, 1024), jnp.float32)
     qt = quantize(w, group_size=64)
     params = {"wq": {"kernel": qt}, "wo": {"kernel": qt}}

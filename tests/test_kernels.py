@@ -5,8 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.costmodel import ASCEND
 from repro.core.quant import quantize
-from repro.kernels import ops, ref
+from repro.kernels import ops, planning, ref
 from repro.kernels.gemm import gemm
 from repro.kernels.w4a16_decoupled import (
     dequant_w4, reduce_partials, splitk_gemm, w4a16_decoupled,
@@ -145,8 +146,10 @@ def test_batched_leading_dims():
                                rtol=1e-5, atol=1e-4)
 
 
-def test_choose_split_k_heuristic():
-    """K≫N with small M (LLM decode) → split; big output tiles → don't."""
+def test_choose_split_k_heuristic(monkeypatch):
+    """K≫N with small M (LLM decode) → split on a many-core chip (the
+    paper's Ascend); big output tiles → don't."""
+    monkeypatch.setattr(planning, "num_cores", lambda: ASCEND.num_cores)
     assert ops.choose_split_k(1, 128, 16384) > 1          # decode regime
     assert ops.choose_split_k(4, 256, 8192) > 1
     assert ops.choose_split_k(2048, 8192, 4096) == 1      # plenty of tiles

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.costmodel import ASCEND
 from repro.core.quant import quantize
 from repro.kernels import ops, planning, ref
 from repro.kernels.planning import (
@@ -144,9 +145,63 @@ def test_planner_refine_uses_tile_search():
         == (bm, bn, bk, s)
 
 
-def test_choose_split_k_decode_regime_and_non_divisible_k():
-    assert choose_split_k(1, 128, 16384) > 1            # decode regime
-    assert choose_split_k(2048, 8192, 4096) == 1        # plenty of tiles
+def test_num_cores_one_per_v5e_chip(monkeypatch):
+    """A kernel runs on one chip: however many v5e chips the host holds,
+    its grid spreads over one TensorCore; an unknown TPU kind raises."""
+    from repro.kernels import common
+
+    class Chip:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [Chip()] * 4)
+    common.target_spec.cache_clear()
+    try:
+        assert planning.num_cores() == 1
+        Chip.device_kind = "TPU v9 imaginary"
+        common.target_spec.cache_clear()
+        with pytest.raises(ValueError, match="no published peaks"):
+            planning.num_cores()
+    finally:
+        monkeypatch.undo()
+        common.target_spec.cache_clear()
+    assert planning.num_cores() == 1       # a CPU host plans for a v5e
+
+
+def test_gspmd_step_on_tpu_plans_no_pallas():
+    """JAX cannot lower a compiled Pallas kernel into one program that
+    GSPMD partitions over several chips: there the planner picks XLA GEMMs
+    and the gather attention path, and refuses a forced fused path. In
+    interpret mode (CPU meshes) the kernels stay eligible."""
+    gemm = MatmulProblem(M=8, N=6912, K=2560, backend="tpu")
+    assert plan_matmul(gemm, use_cache=False).strategy == "fused"
+    spmd = dataclasses.replace(gemm, spmd=True)
+    assert plan_matmul(spmd, use_cache=False).strategy == "xla"
+    mesh = type("Mesh", (), {"shape": {"data": 2, "model": 2},
+                             "axis_names": ("data", "model")})
+    assert planning.shard_problem(gemm, mesh, "col").spmd
+    mesh.shape = {"data": 1, "model": 1}
+    assert not planning.shard_problem(gemm, mesh, "col").spmd
+    assert not planning.spmd_traced()          # no ambient mesh here
+
+    attn = planning.AttentionProblem(B=8, Hq=32, Hkv=8, D=80,
+                                     cache_len=4096, page_size=8,
+                                     backend="tpu")
+    assert planning.plan_attention(attn).path == "fused"
+    attn_spmd = dataclasses.replace(attn, spmd=True)
+    assert planning.plan_attention(attn_spmd).path == "gather"
+    with pytest.raises(ValueError, match="spmd=True"):
+        planning.plan_attention(attn_spmd, path="fused")
+    cpu = dataclasses.replace(attn_spmd, backend="cpu")
+    assert planning.plan_attention(cpu, path="fused").path == "fused"
+
+
+def test_choose_split_k_decode_regime_and_non_divisible_k(monkeypatch):
+    # one v5e TensorCore: a single output tile already occupies the chip
+    assert choose_split_k(1, 128, 16384) == 1
+    monkeypatch.setattr(planning, "num_cores", lambda: ASCEND.num_cores)
+    assert choose_split_k(1, 128, 16384) > 1             # decode regime
+    assert choose_split_k(2048, 8192, 4096) == 1         # plenty of tiles
     # regression: K not divisible by group_size must not split (and must
     # not raise) — the old heuristic assumed divisibility
     assert choose_split_k(1, 128, 16384 + 64, group_size=128) == 1

@@ -88,10 +88,12 @@ def test_new_fused_kernels_are_registered_planner_strategies():
     assert cpu48.strategy == "w4a8_xla"
 
 
-def test_planner_assigns_split_k_to_new_tiled_strategies():
+def test_planner_assigns_split_k_to_new_tiled_strategies(monkeypatch):
     """Splittability is a Strategy attribute, not a name list: the planner
     fills split_k for w4a8_fused in the decode regime (M=1, K ≫ N) exactly
-    as it does for the w4a16 kernels."""
+    as it does for the w4a16 kernels — on a chip with cores to fill (a
+    v5e has one TensorCore, so the count is set here)."""
+    monkeypatch.setattr(planning, "num_cores", lambda: 8)
     plan = planning.plan_matmul(
         MatmulProblem(M=1, N=128, K=16384, group_size=128, backend="tpu",
                       format="w4a8_g128"),
