@@ -243,6 +243,7 @@ def _pooled_partials(qg, positions, start, pool, tables, *, window: int,
         compiler_params=common.compiler_params(
             ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_attention",
     )(bt, qk, qpos, spos, *operands, pool.page_pos[:, None, :])
 
     def per_query(x):

@@ -4,6 +4,15 @@ Layer parameters are stacked along a leading L axis and executed with
 ``jax.lax.scan`` (+ optional remat) so a 126-layer model lowers as one scanned
 layer — essential for dry-run compile times and the standard structure for
 pipeline-friendly HLO.
+
+The serving steps (``decode_step``, ``prefill_chunk_step``, ``verify_step``)
+name their parts with ``jax.named_scope``: ``attn_qkv`` (q/k/v projections
+and RoPE), ``kv_write`` (the KV insert or scatter), ``attn_core`` (attention
+over the cache, its combine included), ``attn_out`` (the o projection),
+``mlp`` (post-attention norm, MLP or experts, residual) and ``head`` (final
+norm and vocabulary projection); ``runtime/steps.py`` adds ``sample``. The
+scopes are metadata only: each compiled op carries its scope in its
+``op_name``, so a device trace can be read by part.
 """
 from __future__ import annotations
 
@@ -404,26 +413,35 @@ def decode_step(params, cfg: ModelConfig, state, tokens: jax.Array,
     kvfmt = get_kv_format(kv_format)
 
     def attn_step(lp, x, kvcache):
-        q = layers.shard_hint(
-            layers.linear(lp["wq"], x, cfg).reshape(B, H, D), "bhd")
-        k = layers.shard_hint(
-            layers.linear(lp["wk"], x, cfg).reshape(B, Hkv, D), "bhd")
-        v = layers.shard_hint(
-            layers.linear(lp["wv"], x, cfg).reshape(B, Hkv, D), "bhd")
-        q = layers.apply_rope(q[:, None], pos[:, None], cfg.rope_theta)[:, 0]
-        k = layers.apply_rope(k[:, None], pos[:, None], cfg.rope_theta)[:, 0]
+        with jax.named_scope("attn_qkv"):
+            q = layers.shard_hint(
+                layers.linear(lp["wq"], x, cfg).reshape(B, H, D), "bhd")
+            k = layers.shard_hint(
+                layers.linear(lp["wk"], x, cfg).reshape(B, Hkv, D), "bhd")
+            v = layers.shard_hint(
+                layers.linear(lp["wv"], x, cfg).reshape(B, Hkv, D), "bhd")
+            q = layers.apply_rope(q[:, None], pos[:, None],
+                                  cfg.rope_theta)[:, 0]
+            k = layers.apply_rope(k[:, None], pos[:, None],
+                                  cfg.rope_theta)[:, 0]
         if tables is None:
-            kvcache = attention.cache_insert(kvcache, k, v, pos)
-            o = attention.decode_attention(q, kvcache, pos,
-                                           window=cfg.sliding_window)
+            with jax.named_scope("kv_write"):
+                kvcache = attention.cache_insert(kvcache, k, v, pos)
+            with jax.named_scope("attn_core"):
+                o = attention.decode_attention(q, kvcache, pos,
+                                               window=cfg.sliding_window)
         else:
-            kvcache = kvc.paged_insert(kvcache, tables, k, v, pos,
-                                       cache_len=cache_len, fmt=kvfmt)
-            o = kvc.paged_decode_attention(
-                q, kvcache, tables, pos, window=cfg.sliding_window,
-                fmt=kvfmt, out_dtype=cfg.dtype, attn_path=attn_path,
-                kv_partitions=kv_partitions, live_pages=live_pages)
-        return layers.linear(lp["wo"], o.reshape(B, H * D), cfg), kvcache
+            with jax.named_scope("kv_write"):
+                kvcache = kvc.paged_insert(kvcache, tables, k, v, pos,
+                                           cache_len=cache_len, fmt=kvfmt)
+            with jax.named_scope("attn_core"):
+                o = kvc.paged_decode_attention(
+                    q, kvcache, tables, pos, window=cfg.sliding_window,
+                    fmt=kvfmt, out_dtype=cfg.dtype, attn_path=attn_path,
+                    kv_partitions=kv_partitions, live_pages=live_pages)
+        with jax.named_scope("attn_out"):
+            out = layers.linear(lp["wo"], o.reshape(B, H * D), cfg)
+        return out, kvcache
 
     def body(h, xs):
         h = layers.shard_hint(h, "bd")
@@ -457,8 +475,7 @@ def decode_step(params, cfg: ModelConfig, state, tokens: jax.Array,
             if active is not None:
                 s_new = jnp.where(active[:, None, None], s_new, ce["ssm"])
             h = h + 0.5 * (a + s_out)
-            h = h + _mlp(lp["mlp"], cfg, _norm(cfg, lp["norm2"], h))
-            return h, {"kv": kvnew, "ssm": s_new}
+            return _ffn_seq(lp, cfg, h), {"kv": kvnew, "ssm": s_new}
         a, kvnew = attn_step(lp["attn"], x1, ce["kv"])
         h = h + a
         if cfg.family == "encdec":
@@ -468,25 +485,15 @@ def decode_step(params, cfg: ModelConfig, state, tokens: jax.Array,
             o = attention.chunked_attention(q, k, v, causal=False, window=0)
             h = h + layers.linear(lp["cross"]["wo"],
                                   o.reshape(B, 1, H * D), cfg)[:, 0]
-        if cfg.family == "moe":
-            y, _ = moe.moe_ffn(
-                lp["moe"], _norm(cfg, lp["norm2"], h),
-                num_experts=cfg.num_experts, top_k=cfg.experts_per_token,
-                capacity_factor=cfg.moe_capacity_factor, cfg=cfg)
-            h = h + y
-        else:
-            h = h + _mlp(lp["mlp"], cfg, _norm(cfg, lp["norm2"], h))
-        return h, {"kv": kvnew}
+        return _ffn_seq(lp, cfg, h), {"kv": kvnew}
 
     xs = (params["layers"], state["cache"])
     if cfg.family == "encdec":
         xs = (params["layers"], state["cache"], state["enc_kv"])
     h, new_cache = jax.lax.scan(body, h, xs)
-    h = _norm(cfg, params["final_norm"], h)
-    if cfg.tie_embeddings:
-        logits = layers.unembed(params["embed"], h)
-    else:
-        logits = layers.linear(params["lm_head"], h, cfg).astype(jnp.float32)
+    with jax.named_scope("head"):
+        logits = _logits_head(params, cfg,
+                              _norm(cfg, params["final_norm"], h))
     new_state = dict(state, cache=new_cache)
     return logits, new_state
 
@@ -515,14 +522,17 @@ def _last_valid_row(h, positions):
 
 
 def _ffn_seq(lp, cfg: ModelConfig, hc):
-    """Post-attention FFN tail shared by the chunk/verify layer bodies."""
-    if cfg.family == "moe":
-        y, _aux = moe.moe_ffn(
-            lp["moe"], _norm(cfg, lp["norm2"], hc),
-            num_experts=cfg.num_experts, top_k=cfg.experts_per_token,
-            capacity_factor=cfg.moe_capacity_factor, cfg=cfg)
-        return hc + y
-    return hc + _mlp(lp["mlp"], cfg, _norm(cfg, lp["norm2"], hc))
+    """Post-attention FFN tail (norm, MLP or experts, residual) shared by
+    the decode/chunk/verify layer bodies: the step programs' ``mlp``
+    scope."""
+    with jax.named_scope("mlp"):
+        if cfg.family == "moe":
+            y, _aux = moe.moe_ffn(
+                lp["moe"], _norm(cfg, lp["norm2"], hc),
+                num_experts=cfg.num_experts, top_k=cfg.experts_per_token,
+                capacity_factor=cfg.moe_capacity_factor, cfg=cfg)
+            return hc + y
+        return hc + _mlp(lp["mlp"], cfg, _norm(cfg, lp["norm2"], hc))
 
 
 def _paged_chunk_attn(ap, cfg: ModelConfig, x1, pool, tables, positions,
@@ -553,44 +563,50 @@ def _paged_chunk_attn(ap, cfg: ModelConfig, x1, pool, tables, positions,
     """
     B, C, _ = x1.shape
     H, Hkv, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    q = layers.shard_hint(
-        layers.linear(ap["wq"], x1, cfg).reshape(B, C, H, D), "bshd")
-    k = layers.shard_hint(
-        layers.linear(ap["wk"], x1, cfg).reshape(B, C, Hkv, D), "bshd")
-    v = layers.shard_hint(
-        layers.linear(ap["wv"], x1, cfg).reshape(B, C, Hkv, D), "bshd")
-    q = layers.apply_rope(q, safe_pos, cfg.rope_theta)
-    k = layers.apply_rope(k, safe_pos, cfg.rope_theta)
-    # the chunk segment takes the same quantize→dequantize round-trip
-    # as its stored copy, so intra-chunk attention sees exactly what
-    # later queries will gather (a no-op for kv_fp16)
-    kr = kv_dequantize(*kv_quantize(k, fmt), fmt=fmt, dtype=cfg.dtype)
-    vr = kv_dequantize(*kv_quantize(v, fmt), fmt=fmt, dtype=cfg.dtype)
-    if attn_path == "fused":
-        from repro.kernels.paged_attention import fused_chunk_attention
+    with jax.named_scope("attn_qkv"):
+        q = layers.shard_hint(
+            layers.linear(ap["wq"], x1, cfg).reshape(B, C, H, D), "bshd")
+        k = layers.shard_hint(
+            layers.linear(ap["wk"], x1, cfg).reshape(B, C, Hkv, D), "bshd")
+        v = layers.shard_hint(
+            layers.linear(ap["wv"], x1, cfg).reshape(B, C, Hkv, D), "bshd")
+        q = layers.apply_rope(q, safe_pos, cfg.rope_theta)
+        k = layers.apply_rope(k, safe_pos, cfg.rope_theta)
+    with jax.named_scope("attn_core"):
+        # the chunk segment takes the same quantize→dequantize round-trip
+        # as its stored copy, so intra-chunk attention sees exactly what
+        # later queries will gather (a no-op for kv_fp16)
+        kr = kv_dequantize(*kv_quantize(k, fmt), fmt=fmt, dtype=cfg.dtype)
+        vr = kv_dequantize(*kv_quantize(v, fmt), fmt=fmt, dtype=cfg.dtype)
+        if attn_path == "fused":
+            from repro.kernels.paged_attention import fused_chunk_attention
 
-        o = fused_chunk_attention(
-            q, kr, vr, pool, tables, positions,
-            window=cfg.sliding_window, fmt=fmt, out_dtype=cfg.dtype,
-            kv_partitions=kv_partitions)
-    else:
-        win = kvc.gather_window(pool, tables, fmt=fmt, out_dtype=cfg.dtype,
-                                live_pages=live_pages)
-        start = positions[:, :1]                      # first chunk pos
-        wpos = jnp.where(win.pos < start, win.pos, -1)
-        seq = attention.KVCache(
-            k=jnp.concatenate([win.k, kr.astype(win.k.dtype)], axis=1),
-            v=jnp.concatenate([win.v, vr.astype(win.v.dtype)], axis=1),
-            pos=jnp.concatenate([wpos, positions], axis=1))
-        o = attention.prefix_chunk_attention(q, seq, positions,
-                                             window=cfg.sliding_window)
-    if batched:
-        pool = kvc.scatter_chunks(pool, tables, k, v, positions,
-                                  cache_len=cache_len, fmt=fmt)
-    else:
-        pool = kvc.scatter_chunk(pool, tables[0], k[0], v[0], positions[0],
-                                 cache_len=cache_len, fmt=fmt)
-    a = layers.linear(ap["wo"], o.reshape(B, C, H * D), cfg)
+            o = fused_chunk_attention(
+                q, kr, vr, pool, tables, positions,
+                window=cfg.sliding_window, fmt=fmt, out_dtype=cfg.dtype,
+                kv_partitions=kv_partitions)
+        else:
+            win = kvc.gather_window(pool, tables, fmt=fmt,
+                                    out_dtype=cfg.dtype,
+                                    live_pages=live_pages)
+            start = positions[:, :1]                      # first chunk pos
+            wpos = jnp.where(win.pos < start, win.pos, -1)
+            seq = attention.KVCache(
+                k=jnp.concatenate([win.k, kr.astype(win.k.dtype)], axis=1),
+                v=jnp.concatenate([win.v, vr.astype(win.v.dtype)], axis=1),
+                pos=jnp.concatenate([wpos, positions], axis=1))
+            o = attention.prefix_chunk_attention(q, seq, positions,
+                                                 window=cfg.sliding_window)
+    with jax.named_scope("kv_write"):
+        if batched:
+            pool = kvc.scatter_chunks(pool, tables, k, v, positions,
+                                      cache_len=cache_len, fmt=fmt)
+        else:
+            pool = kvc.scatter_chunk(pool, tables[0], k[0], v[0],
+                                     positions[0], cache_len=cache_len,
+                                     fmt=fmt)
+    with jax.named_scope("attn_out"):
+        a = layers.linear(ap["wo"], o.reshape(B, C, H * D), cfg)
     return layers.shard_hint(a, "bsd"), pool
 
 
@@ -732,8 +748,9 @@ def prefill_chunk_step(params, cfg: ModelConfig, state, h: jax.Array,
         h, new_pool = jax.lax.scan(body, h, (params["layers"], cache["kv"]))
         new_state = dict(state, cache=dict(cache, kv=new_pool))
 
-    h = _norm(cfg, params["final_norm"], h)
-    logits = _logits_head(params, cfg, _last_valid_row(h, positions))
+    with jax.named_scope("head"):
+        h = _norm(cfg, params["final_norm"], h)
+        logits = _logits_head(params, cfg, _last_valid_row(h, positions))
     return logits, new_state
 
 
@@ -865,8 +882,9 @@ def verify_step(params, cfg: ModelConfig, state, tokens: jax.Array,
         h, new_pool = jax.lax.scan(body, h, (params["layers"], cache["kv"]))
         new_state = dict(state, cache=dict(cache, kv=new_pool))
 
-    h = _norm(cfg, params["final_norm"], h)
-    logits = _logits_head(params, cfg, h)
+    with jax.named_scope("head"):
+        logits = _logits_head(params, cfg,
+                              _norm(cfg, params["final_norm"], h))
     return logits, new_state, carries
 
 

@@ -70,6 +70,12 @@ from repro.runtime import steps as rsteps
 __all__ = ["Request", "ServeReport", "ServingEngine", "StepEvents",
            "insert_slot", "reset_slot"]
 
+_NO_SPAN = contextlib.nullcontext()
+# counts a step tallies as it goes; admitted/finished come from its events
+_TALLIES = ("decode_rows", "prefill_chunks", "prefill_tokens",
+            "pages_allocated")
+_PATH_CODE = {"ring": 0, "gather": 1, "fused": 2}
+
 
 @dataclasses.dataclass(frozen=True)
 class Request:
@@ -245,6 +251,12 @@ class ServingEngine:
     ``mesh=None`` runs single-device (plain ``jax.jit``); with a mesh the
     steps are jitted with explicit shardings and the kernel plans are
     chosen shard-local (see module docstring).
+
+    ``spans`` (also settable as ``engine.spans``) is an optional
+    :class:`~repro.runtime.metrics.SpanRecorder`: with one, every
+    :meth:`step` records ``serve.step`` and its phases, with the work
+    counted at each (see docs/serving.md, "Spans"); without, each span
+    site costs one ``None`` check.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, mesh=None,
@@ -257,7 +269,8 @@ class ServingEngine:
                  warm_cache_mb: float = 0.0,
                  speculate=None, spec_k: int = 4,
                  admission: str = "fifo",
-                 attn_path: str = "auto"):
+                 attn_path: str = "auto",
+                 spans: Optional[rmetrics.SpanRecorder] = None):
         self.mesh = mesh
         if admission not in ("fifo", "priority"):
             raise ValueError(f"admission must be 'fifo' or 'priority', "
@@ -436,6 +449,9 @@ class ServingEngine:
 
         # re-entrant stepper state (armed by start(); run() is a wrapper)
         self.metrics: Optional[rmetrics.MetricsRegistry] = None
+        self.spans = spans
+        # work of the current step, put on its serve.step span
+        self._tally = dict.fromkeys(_TALLIES, 0)
         self.report: Optional[ServeReport] = None
         self._started = False
         self._waiting: collections.deque = collections.deque()
@@ -452,6 +468,13 @@ class ServingEngine:
     def _ctx(self):
         return jax.set_mesh(self.mesh) if self.mesh is not None \
             else contextlib.nullcontext()
+
+    def _span(self, name: str, rid: Optional[int] = None,
+              step: Optional[int] = None):
+        """A span of the recorder, or a no-op context (yielding None)
+        when tracing is off."""
+        rec = self.spans
+        return _NO_SPAN if rec is None else rec.span(name, rid, step)
 
     def _prefill_inputs(self, req: Request):
         prompt = jnp.asarray(req.prompt, jnp.int32)[None]
@@ -710,11 +733,13 @@ class ServingEngine:
             bid = int(tbl[p])
             if bid < 0:
                 tbl[p] = self._slot_alloc(i)
+                self._tally["pages_allocated"] += 1
                 if txn is not None:
                     txn.append(("alloc", p, int(tbl[p])))
             elif self.alloc.refcount(bid) > 1:
                 new = self.alloc.cow(bid)
                 self._consume_reserve(i)
+                self._tally["pages_allocated"] += 1
                 state = self._pool_map(
                     state, lambda pool: kvc.copy_blocks(pool, bid, new))
                 tbl[p] = new
@@ -920,8 +945,6 @@ class ServingEngine:
         rows here instead of argmax'ing one by one — one device-side
         argmax over the stacked rows and ONE host transfer replaces a
         per-slot sync chain."""
-        if not pending:
-            return
         if len(pending) == 1:
             slot, row = pending[0]
             slot.emit_first(int(jnp.argmax(row)))
@@ -1047,7 +1070,8 @@ class ServingEngine:
         return state, slot, dirty
 
     def _advance_prefill(self, state, i: int, slot: _Slot, pending):
-        """Run one prefill chunk for slot ``i``; returns (state, dirty)."""
+        """Run one prefill chunk for slot ``i``; returns (state, number of
+        prompt positions it computed)."""
         C = self.prefill_chunk
         if self.paged:
             self._share_ahead(i, slot)
@@ -1088,7 +1112,7 @@ class ServingEngine:
             pending.append((slot, res["logits"][0]))
         elif self.paged:
             self._publish_keys(i, slot, upto=end)
-        return state, False
+        return state, n
 
     # -- scheduler ---------------------------------------------------------
 
@@ -1146,6 +1170,7 @@ class ServingEngine:
         self._step_no = 0
         self._events = None
         self._started = True
+        self._path_gauges()
 
     def submit(self, req: Request) -> None:
         """Queue ``req`` for admission (validated now, admitted by a later
@@ -1279,28 +1304,32 @@ class ServingEngine:
             m.gauge("engine_warm_pages",
                     "refcount-0 prefix blocks retained warm").set(
                 self.alloc.warm_pages)
-        # which decode-attention path served this step (planner outcome,
-        # surfaced on GET /metrics): 0=ring, 1=gather, 2=fused
-        m.gauge("engine_attn_path",
-                "decode attention path (0=ring 1=gather 2=fused)").set(
-            {"ring": 0, "gather": 1, "fused": 2}.get(self.attn_path, -1))
         m.counter(f"engine_attn_path_steps_{self.attn_path}",
                   "scheduler steps served by this attention path").inc()
-        path_code = {"ring": 0, "gather": 1, "fused": 2}
-        if self.chunked:
-            m.gauge("engine_prefill_attn_path",
-                    "chunked-prefill attention path "
-                    "(0=ring 1=gather 2=fused)").set(
-                path_code.get(self.prefill_attn_path, -1))
-        if self.proposer is not None:
-            m.gauge("engine_verify_attn_path",
-                    "speculative-verify attention path "
-                    "(0=ring 1=gather 2=fused)").set(
-                path_code.get(self.verify_attn_path, -1))
         if self.proposer is not None and self.report is not None:
             m.gauge("engine_acceptance_rate",
                     "accepted/proposed draft tokens").set(
                 self.report.acceptance_rate)
+
+    def _path_gauges(self) -> None:
+        """The planner's attention paths, fixed for the engine's life,
+        surfaced on GET /metrics: 0=ring, 1=gather, 2=fused."""
+        m = self.metrics
+        if m is None:
+            return
+        m.gauge("engine_attn_path",
+                "decode attention path (0=ring 1=gather 2=fused)").set(
+            _PATH_CODE.get(self.attn_path, -1))
+        if self.chunked:
+            m.gauge("engine_prefill_attn_path",
+                    "chunked-prefill attention path "
+                    "(0=ring 1=gather 2=fused)").set(
+                _PATH_CODE.get(self.prefill_attn_path, -1))
+        if self.proposer is not None:
+            m.gauge("engine_verify_attn_path",
+                    "speculative-verify attention path "
+                    "(0=ring 1=gather 2=fused)").set(
+                _PATH_CODE.get(self.verify_attn_path, -1))
 
     def step(self, *, verbose: bool = False) -> StepEvents:
         """One scheduler iteration: admit arrived requests into free slots,
@@ -1316,9 +1345,14 @@ class ServingEngine:
             ev.worked = False
             return ev
         self._events = ev
+        self._tally = dict.fromkeys(_TALLIES, 0)
         try:
-            with self._ctx():
+            with self._span("serve.step", step=self._step_no) as sp, \
+                    self._ctx():
                 decode_dt = self._step_body(ev, verbose)
+                if sp is not None:
+                    sp.counts.update(self._tally, admitted=len(ev.admitted),
+                                     finished=len(ev.finished))
         finally:
             self._events = None
         self.report.steps = self._step_no
@@ -1355,25 +1389,26 @@ class ServingEngine:
                                     # allocator reclaims them on demand)
             del self._waiting[idx]
             req = cand
-            t0 = time.perf_counter()
-            if self.chunked:
-                state, slot, d = self._admit_chunked(
-                    state, req, i, t0, pending)
-                state_dirty |= d
-            else:
-                inputs = self._prefill_inputs(req)
-                logits, rstate = self._prefill_fn(inputs)(
-                    self.params, inputs)
-                state = insert_slot(state, rstate, i)
-                state_dirty = True
-                slot = _Slot(req, self.pos0(req), t0)
-                pending.append((slot, logits[0]))
-            if proposer is not None:
-                slot.prompt_ids = [
-                    int(t) for t in
-                    np.asarray(req.prompt).reshape(-1)]
-                proposer.admit(self, i, slot)
-            report.prefill_s += time.perf_counter() - t0
+            with self._span("serve.admit", req.rid):
+                t0 = time.perf_counter()
+                if self.chunked:
+                    state, slot, d = self._admit_chunked(
+                        state, req, i, t0, pending)
+                    state_dirty |= d
+                else:
+                    inputs = self._prefill_inputs(req)
+                    logits, rstate = self._prefill_fn(inputs)(
+                        self.params, inputs)
+                    state = insert_slot(state, rstate, i)
+                    state_dirty = True
+                    slot = _Slot(req, self.pos0(req), t0)
+                    pending.append((slot, logits[0]))
+                if proposer is not None:
+                    slot.prompt_ids = [
+                        int(t) for t in
+                        np.asarray(req.prompt).reshape(-1)]
+                    proposer.admit(self, i, slot)
+                report.prefill_s += time.perf_counter() - t0
             report.admitted += 1
             slots[i] = slot
             ev.admitted.append(req.rid)
@@ -1389,15 +1424,22 @@ class ServingEngine:
         for i, s in enumerate(slots):
             if s is not None and s.phase == "prefill" \
                     and s.pf_stream is not None:
-                t0 = time.perf_counter()
-                if state_dirty:
-                    state = self._constrain_state(state)
-                    state_dirty = False
-                state, d = self._advance_prefill(state, i, s,
-                                                 pending)
-                state_dirty |= d
-                report.prefill_s += time.perf_counter() - t0
-        self._flush_first_tokens(pending)
+                with self._span("serve.prefill_chunk", s.req.rid) as sp:
+                    t0 = time.perf_counter()
+                    if state_dirty:
+                        state = self._constrain_state(state)
+                        state_dirty = False
+                    state, n = self._advance_prefill(state, i, s, pending)
+                    report.prefill_s += time.perf_counter() - t0
+                    self._tally["prefill_chunks"] += 1
+                    self._tally["prefill_tokens"] += n
+                    if sp is not None:
+                        sp.counts["tokens"] = n
+        if pending:
+            with self._span("serve.first_tokens") as sp:
+                if sp is not None:
+                    sp.counts["rows"] = len(pending)
+                self._flush_first_tokens(pending)
 
         # -- settle freshly-activated slots ------------------------
         for i, s in enumerate(slots):
@@ -1417,117 +1459,132 @@ class ServingEngine:
                 self._step_no = step + 1
             return decode_dt
 
+        self._tally["decode_rows"] = len(active)
         # -- speculative: propose → verify → accept → rollback -----
+        # (drafting counts as building the verify step's inputs)
         if proposer is not None:
             k = self.spec_k
-            views = [spec.ProposalView(
-                i, slots[i].prompt_ids + slots[i].tokens,
-                int(pos[i])) for i in active]
-            t0 = time.perf_counter()
-            proposals = proposer.propose(views, k)
             C = k + 1
-            ptok = np.zeros((self.max_batch, C), np.int32)
-            ppos = np.full((self.max_batch, C), -1, np.int32)
             n_drafts: Dict[int, int] = {}
             txns: Dict[int, list] = {}
-            for i in active:
-                s = slots[i]
-                props = list(proposals.get(i, []))[:k]
-                # clamp: (a) never emit past the request budget,
-                # (b) never let the draft overhang wrap the logical
-                # window — a wrapped speculative write would destroy
-                # a still-in-window entry, where plain decode only
-                # ever overwrites the exactly-expiring one
-                n = min(len(props), s.remaining - 1)
-                if int(pos[i]) + n >= self.cache_len:
-                    n = max(0, self.cache_len - 1 - int(pos[i]))
-                n_drafts[i] = n
-                report.proposed_tokens += n
-                ptok[i, 0], ppos[i, 0] = tok[i], pos[i]
-                for j in range(n):
-                    ptok[i, j + 1] = int(props[j])
-                    ppos[i, j + 1] = int(pos[i]) + j + 1
-                txns[i] = []
-                if self.paged:
-                    state, d = self._ensure_pages(
-                        state, i,
-                        [p % self.cache_len for p in
-                         range(int(pos[i]), int(pos[i]) + n + 1)],
-                        txn=txns[i])
-                    state_dirty |= d
+            with self._span("serve.inputs"):
+                views = [spec.ProposalView(
+                    i, slots[i].prompt_ids + slots[i].tokens,
+                    int(pos[i])) for i in active]
+                t0 = time.perf_counter()
+                proposals = proposer.propose(views, k)
+                ptok = np.zeros((self.max_batch, C), np.int32)
+                ppos = np.full((self.max_batch, C), -1, np.int32)
+                for i in active:
+                    s = slots[i]
+                    props = list(proposals.get(i, []))[:k]
+                    # clamp: (a) never emit past the request budget,
+                    # (b) never let the draft overhang wrap the logical
+                    # window — a wrapped speculative write would destroy
+                    # a still-in-window entry, where plain decode only
+                    # ever overwrites the exactly-expiring one
+                    n = min(len(props), s.remaining - 1)
+                    if int(pos[i]) + n >= self.cache_len:
+                        n = max(0, self.cache_len - 1 - int(pos[i]))
+                    n_drafts[i] = n
+                    report.proposed_tokens += n
+                    ptok[i, 0], ppos[i, 0] = tok[i], pos[i]
+                    for j in range(n):
+                        ptok[i, j + 1] = int(props[j])
+                        ppos[i, j + 1] = int(pos[i]) + j + 1
+                    txns[i] = []
             if self.paged:
+                with self._span("serve.pages") as sp:
+                    a0 = self._tally["pages_allocated"]
+                    for i in active:
+                        state, d = self._ensure_pages(
+                            state, i,
+                            [p % self.cache_len for p in
+                             range(int(pos[i]),
+                                   int(pos[i]) + n_drafts[i] + 1)],
+                            txn=txns[i])
+                        state_dirty |= d
+                    if sp is not None:
+                        sp.counts["allocated"] = \
+                            self._tally["pages_allocated"] - a0
                 report.peak_pages = max(report.peak_pages,
                                         self.alloc.pages_in_use)
-            if state_dirty:
-                state = self._constrain_state(state)
-                state_dirty = False
-            vinputs = {
-                "tokens": jnp.asarray(ptok),
-                "positions": jnp.asarray(ppos),
-            }
-            if self.paged:
-                step_tables = self._tables.copy()
-                for i, s in enumerate(slots):
-                    if s is None or s.phase != "active":
-                        step_tables[i] = -1
-                vinputs["tables"] = jnp.asarray(step_tables)
-            lp = None
-            if self.paged and self.verify_attn_path == "gather":
-                mx = max(int(pos[i]) for i in active)
-                if mx + k < self.cache_len:
-                    # gather reads pool entries < positions[:, 0] only
-                    # (the k+1 in-flight rows are the segment), so the
-                    # live high-water mark is ceil(max_pos / page_size)
-                    lp = self._live_bucket(
-                        max(1, -(-mx // self.page_size)))
-            res = self._verify_step(lp)(self.params, state, vinputs)
-            state = res["state"]
-            nxt = np.asarray(res["next"])          # (B, C)
+            with self._span("serve.inputs"):
+                if state_dirty:
+                    state = self._constrain_state(state)
+                    state_dirty = False
+                vinputs = {
+                    "tokens": jnp.asarray(ptok),
+                    "positions": jnp.asarray(ppos),
+                }
+                if self.paged:
+                    step_tables = self._tables.copy()
+                    for i, s in enumerate(slots):
+                        if s is None or s.phase != "active":
+                            step_tables[i] = -1
+                    vinputs["tables"] = jnp.asarray(step_tables)
+                lp = None
+                if self.paged and self.verify_attn_path == "gather":
+                    mx = max(int(pos[i]) for i in active)
+                    if mx + k < self.cache_len:
+                        # gather reads pool entries < positions[:, 0]
+                        # only (the k+1 in-flight rows are the segment),
+                        # so the live high-water mark is
+                        # ceil(max_pos / page_size)
+                        lp = self._live_bucket(
+                            max(1, -(-mx // self.page_size)))
+            with self._span("serve.dispatch"):
+                res = self._verify_step(lp)(self.params, state, vinputs)
+                state = res["state"]
+            with self._span("serve.readback"):
+                nxt = np.asarray(res["next"])          # (B, C)
             dt = time.perf_counter() - t0
             report.decode_s += dt
             decode_dt = dt
             emitted_total = 0
-            # exact greedy acceptance: draft j survives iff it equals
-            # the target's own argmax at position j-1; the first
-            # mismatch position contributes the target's choice as the
-            # bonus token
-            accepted: Dict[int, int] = {}
-            for i in active:
-                a = 0
-                while a < n_drafts[i] and \
-                        int(ptok[i, a + 1]) == int(nxt[i, a]):
-                    a += 1
-                accepted[i] = a
-            carries = res.get("carries")
-            if carries is not None:
-                # recurrent families: commit each row's carry at its
-                # accepted frontier (checkpoint 1 + accepted consumed
-                # positions; 0 restores inactive rows untouched)
-                sel = np.zeros(self.max_batch, np.int32)
+            with self._span("serve.collect"):
+                # exact greedy acceptance: draft j survives iff it equals
+                # the target's own argmax at position j-1; the first
+                # mismatch position contributes the target's choice as
+                # the bonus token
+                accepted: Dict[int, int] = {}
                 for i in active:
-                    sel[i] = accepted[i] + 1
-                state = self._apply_carry_selection(state, carries, sel)
-                state_dirty = True
-            for i in active:
-                s = slots[i]
-                a = accepted[i]
-                emitted = [int(nxt[i, j]) for j in range(a + 1)]
-                report.accepted_tokens += a
-                if self.paged:
-                    state, d = self._rollback_pages(
-                        state, i, txns[i],
-                        ((int(pos[i]) + a) % self.cache_len)
-                        // self.page_size)
-                    state_dirty |= d
-                emitted_total += len(emitted)
-                s.tokens.extend(emitted)
-                ev.emitted.setdefault(s.req.rid, []).extend(emitted)
-                s.remaining -= len(emitted)
-                s.pos_next += len(emitted)
-                tok[i], pos[i] = emitted[-1], s.pos_next
-                if s.remaining == 0:
-                    state, d = self._finish(state, i, s)
-                    state_dirty |= d
+                    a = 0
+                    while a < n_drafts[i] and \
+                            int(ptok[i, a + 1]) == int(nxt[i, a]):
+                        a += 1
+                    accepted[i] = a
+                carries = res.get("carries")
+                if carries is not None:
+                    # recurrent families: commit each row's carry at its
+                    # accepted frontier (checkpoint 1 + accepted consumed
+                    # positions; 0 restores inactive rows untouched)
+                    sel = np.zeros(self.max_batch, np.int32)
+                    for i in active:
+                        sel[i] = accepted[i] + 1
+                    state = self._apply_carry_selection(state, carries,
+                                                        sel)
+                    state_dirty = True
+                for i in active:
+                    s = slots[i]
+                    a = accepted[i]
+                    emitted = [int(nxt[i, j]) for j in range(a + 1)]
+                    report.accepted_tokens += a
+                    if self.paged:
+                        state, d = self._rollback_pages(
+                            state, i, txns[i],
+                            ((int(pos[i]) + a) % self.cache_len)
+                            // self.page_size)
+                        state_dirty |= d
+                    emitted_total += len(emitted)
+                    s.tokens.extend(emitted)
+                    ev.emitted.setdefault(s.req.rid, []).extend(emitted)
+                    s.remaining -= len(emitted)
+                    s.pos_next += len(emitted)
+                    tok[i], pos[i] = emitted[-1], s.pos_next
+                    if s.remaining == 0:
+                        state, d = self._finish(state, i, s)
+                        state_dirty |= d
             report.decode_tokens += emitted_total
             report.step_records.append({
                 "step": step, "active": len(active),
@@ -1542,51 +1599,60 @@ class ServingEngine:
 
         # -- one batched decode step over every slot ---------------
         if self.paged:
-            for i in active:
-                state, d = self._ensure_pages(
-                    state, i, [int(pos[i]) % self.cache_len])
-                state_dirty |= d
+            with self._span("serve.pages") as sp:
+                a0 = self._tally["pages_allocated"]
+                for i in active:
+                    state, d = self._ensure_pages(
+                        state, i, [int(pos[i]) % self.cache_len])
+                    state_dirty |= d
+                if sp is not None:
+                    sp.counts["allocated"] = \
+                        self._tally["pages_allocated"] - a0
             report.peak_pages = max(report.peak_pages,
                                     self.alloc.pages_in_use)
-        if state_dirty:
-            # eager insert/reset/scatter ops re-committed leaves
-            # off the serve shardings; steady-state steps skip this
-            # (the serve output already carries its out_shardings)
-            state = self._constrain_state(state)
-            state_dirty = False
-        t0 = time.perf_counter()
-        inputs = {
-            "state": state,
-            "tokens": jnp.asarray(tok),
-            "pos": jnp.asarray(pos),
-        }
-        if self._needs_active:
-            # a decode step must not advance the recurrent carries of
-            # rows that are free or still mid-chunked-prefill
-            act = np.zeros(self.max_batch, bool)
-            for i in active:
-                act[i] = True
-            inputs["active"] = jnp.asarray(act)
-        if self.paged:
-            # non-active rows (free, or mid-chunked-prefill) are
-            # masked to -1: their stale tok/pos writes redirect to
-            # the null block instead of corrupting real pages (the
-            # ring engine was immune — each slot owned its row)
-            step_tables = self._tables.copy()
-            for i, s in enumerate(slots):
-                if s is None or s.phase != "active":
-                    step_tables[i] = -1
-            inputs["tables"] = jnp.asarray(step_tables)
-        lp = None
-        if self.paged and self.attn_path == "gather":
-            mx = max(int(pos[i]) for i in active)
-            if mx < self.cache_len:
-                # insert-before-attend: the step writes position mx and
-                # reads entries <= mx, so the high water is ceil((mx+1)/ps)
-                lp = self._live_bucket(-(-(mx + 1) // self.page_size))
-        res = self._serve_step(lp)(self.params, inputs)
-        state = res["state"]
-        nxt = np.asarray(res["next"])
+        with self._span("serve.inputs"):
+            if state_dirty:
+                # eager insert/reset/scatter ops re-committed leaves
+                # off the serve shardings; steady-state steps skip this
+                # (the serve output already carries its out_shardings)
+                state = self._constrain_state(state)
+                state_dirty = False
+            t0 = time.perf_counter()
+            inputs = {
+                "state": state,
+                "tokens": jnp.asarray(tok),
+                "pos": jnp.asarray(pos),
+            }
+            if self._needs_active:
+                # a decode step must not advance the recurrent carries of
+                # rows that are free or still mid-chunked-prefill
+                act = np.zeros(self.max_batch, bool)
+                for i in active:
+                    act[i] = True
+                inputs["active"] = jnp.asarray(act)
+            if self.paged:
+                # non-active rows (free, or mid-chunked-prefill) are
+                # masked to -1: their stale tok/pos writes redirect to
+                # the null block instead of corrupting real pages (the
+                # ring engine was immune — each slot owned its row)
+                step_tables = self._tables.copy()
+                for i, s in enumerate(slots):
+                    if s is None or s.phase != "active":
+                        step_tables[i] = -1
+                inputs["tables"] = jnp.asarray(step_tables)
+            lp = None
+            if self.paged and self.attn_path == "gather":
+                mx = max(int(pos[i]) for i in active)
+                if mx < self.cache_len:
+                    # insert-before-attend: the step writes position mx
+                    # and reads entries <= mx, so the high water is
+                    # ceil((mx+1)/ps)
+                    lp = self._live_bucket(-(-(mx + 1) // self.page_size))
+        with self._span("serve.dispatch"):
+            res = self._serve_step(lp)(self.params, inputs)
+            state = res["state"]
+        with self._span("serve.readback"):
+            nxt = np.asarray(res["next"])
         dt = time.perf_counter() - t0
         report.decode_s += dt
         decode_dt = dt
@@ -1599,16 +1665,17 @@ class ServingEngine:
                   f"admitted={admitted} {dt*1e3:.2f} ms")
 
         # -- collect tokens; evict finished slots ------------------
-        for i in active:
-            s = slots[i]
-            s.tokens.append(int(nxt[i]))
-            ev.emitted.setdefault(s.req.rid, []).append(int(nxt[i]))
-            s.remaining -= 1
-            s.pos_next += 1
-            tok[i], pos[i] = nxt[i], s.pos_next
-            if s.remaining == 0:
-                state, d = self._finish(state, i, s)
-                state_dirty |= d
+        with self._span("serve.collect"):
+            for i in active:
+                s = slots[i]
+                s.tokens.append(int(nxt[i]))
+                ev.emitted.setdefault(s.req.rid, []).append(int(nxt[i]))
+                s.remaining -= 1
+                s.pos_next += 1
+                tok[i], pos[i] = nxt[i], s.pos_next
+                if s.remaining == 0:
+                    state, d = self._finish(state, i, s)
+                    state_dirty |= d
         self._state, self._state_dirty = state, state_dirty
         self._step_no = step + 1
         return decode_dt

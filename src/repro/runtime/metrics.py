@@ -1,4 +1,5 @@
-"""Serving metrics plane: counters, gauges, histograms + percentiles.
+"""Serving metrics plane: counters, gauges, histograms + percentiles, and
+host spans.
 
 One small registry shared by the serving stack: the engine samples it once
 per :meth:`ServingEngine.step` (queue depth, active slots, pages in use,
@@ -10,17 +11,29 @@ format. The same nearest-rank percentile helpers back
 ``ServeReport`` and the ``/metrics`` endpoint can never disagree on what
 "p99" means.
 
-No external dependency — stdlib only, like the rest of the runtime.
+:class:`SpanRecorder` is the tracing beside the registry: a bounded
+in-memory record of the engine's host spans (``serve.step`` and its
+phases, see ``docs/serving.md``), each also entered as a
+``jax.profiler.TraceAnnotation`` so that a profiled slice carries it on the
+device trace's clock, plus the process's backend compiles and GC passes.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import dataclasses
+import gc
+import itertools
 import math
 import threading
-from typing import Dict, Iterable, List, Optional, Sequence
+import time
+from typing import Any, Deque, Dict, Iterable, List, Optional, Sequence
+
+import jax
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry",
-    "nearest_rank", "summarize",
+    "Counter", "Gauge", "Histogram", "MetricsRegistry", "Span",
+    "SpanRecorder", "nearest_rank", "summarize",
 ]
 
 QUANTILES = (0.5, 0.95, 0.99)
@@ -203,3 +216,127 @@ class MetricsRegistry:
         for name in sorted(self._metrics):
             lines.extend(self._metrics[name].render())
         return "\n".join(lines) + "\n"
+
+
+@dataclasses.dataclass
+class Span:
+    """One host span, ``[start_ns, end_ns)`` on ``time.perf_counter_ns``.
+
+    ``parent`` is the id of the span open around it on its thread (None at
+    the top), ``step`` the engine step it belongs to, ``rid`` the request
+    it serves where there is one; ``counts`` holds the work counted at that
+    boundary (and, on ``serve.compile``, the compiled function's name)."""
+
+    name: str
+    start_ns: int
+    end_ns: int
+    id: int
+    parent: Optional[int]
+    step: Optional[int]
+    rid: Optional[int] = None
+    counts: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+    @property
+    def dur_ns(self) -> int:
+        return self.end_ns - self.start_ns
+
+
+class SpanRecorder:
+    """Bounded in-memory record of host spans: the newest ``capacity``
+    closed spans, in the order they closed.
+
+    :meth:`span` times a block and enters ``jax.profiler.TraceAnnotation``
+    of the same name around it (with ``step`` and ``rid``), so a profiled
+    slice carries the span on the device trace's clock. Beside the caller's
+    spans the recorder adds, while it is open, one ``serve.compile`` per
+    XLA backend compile (JAX's ``backend_compile_duration`` event, with
+    ``fun_name``) and one ``serve.gc`` per Python GC pass (``gc.callbacks``,
+    with ``generation``), each a child of the span open on its thread when
+    it ended. :meth:`close` (or leaving the ``with`` block) removes those
+    hooks."""
+
+    COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self, capacity: int = 1 << 16):
+        self.spans: Deque[Span] = collections.deque(maxlen=int(capacity))
+        self.step: Optional[int] = None
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+        self._gc_start: Optional[int] = None
+        gc.callbacks.append(self._on_gc)
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+        self._hooked = True
+
+    def close(self) -> None:
+        if self._hooked:
+            gc.callbacks.remove(self._on_gc)
+            jax.monitoring.unregister_event_duration_listener(self._on_event)
+            self._hooked = False
+
+    def snapshot(self) -> List[Span]:
+        """The recorded spans as a list. While the recorder is open a GC
+        pass can append to ``spans`` at any allocation, so iterate over
+        this copy, taken with the collector off, and not over ``spans``."""
+        was = gc.isenabled()
+        gc.disable()
+        try:
+            return list(self.spans)
+        finally:
+            if was:
+                gc.enable()
+
+    def __enter__(self) -> "SpanRecorder":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+    def _stack(self) -> List[Span]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    @contextlib.contextmanager
+    def span(self, name: str, rid: Optional[int] = None,
+             step: Optional[int] = None):
+        """Record ``name`` around the block; yields the :class:`Span`, whose
+        ``counts`` the block may fill. ``step`` sets the recorder's current
+        step number, which this and every later span carries."""
+        if step is not None:
+            self.step = step
+        stack = self._stack()
+        s = Span(name, 0, 0, next(self._ids),
+                 stack[-1].id if stack else None, self.step, rid)
+        tags = {} if self.step is None else {"step": self.step}
+        if rid is not None:
+            tags["rid"] = rid
+        stack.append(s)
+        try:
+            with jax.profiler.TraceAnnotation(name, **tags):
+                s.start_ns = time.perf_counter_ns()
+                yield s
+        finally:
+            s.end_ns = time.perf_counter_ns()
+            stack.pop()
+            self.spans.append(s)
+
+    def _add(self, name: str, start_ns: int, end_ns: int, **counts) -> None:
+        stack = self._stack()
+        self.spans.append(Span(name, start_ns, end_ns, next(self._ids),
+                               stack[-1].id if stack else None, self.step,
+                               counts=counts))
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            self._gc_start = time.perf_counter_ns()
+        elif self._gc_start is not None:
+            self._add("serve.gc", self._gc_start, time.perf_counter_ns(),
+                      generation=info.get("generation"))
+            self._gc_start = None
+
+    def _on_event(self, event: str, duration: float, **kw) -> None:
+        if event == self.COMPILE_EVENT:
+            end = time.perf_counter_ns()
+            self._add("serve.compile", end - int(duration * 1e9), end,
+                      fun_name=str(kw.get("fun_name", "?")))

@@ -137,7 +137,8 @@ def make_serve_step(cfg: ModelConfig, *, cache_len: int = 0,
             tables=inputs.get("tables"), active=inputs.get("active"),
             cache_len=cache_len, kv_format=kv_format, attn_path=attn_path,
             kv_partitions=kv_partitions, live_pages=live_pages)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return {"next": next_tok, "logits": logits, "state": state}
     return serve_step
 
@@ -191,7 +192,8 @@ def make_verify_step(cfg: ModelConfig, cache_len: int, *,
             inputs.get("tables"), cache_len=cache_len, kv_format=kv_format,
             attn_path=attn_path, kv_partitions=kv_partitions,
             live_pages=live_pages)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         out = {"next": next_tok, "logits": logits, "state": state}
         if carries is not None:
             out["carries"] = carries

@@ -6,12 +6,17 @@ main-path kernel at h2o-danube-1.8b widths (d_model 2560, d_ff 6912,
 GQA 32/8, head_dim 80, page_size 8, prefill chunk 32) for one chip of a
 ``v5e:2x2`` topology that is described, not attached, and assert that the
 Mosaic kernel survives into the compiled program (``tpu_custom_call``).
+One test compiles the engine's whole decode step, to check the named
+scopes the chip benchmark reads and the ops its kernel jobs assign.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import dataclasses
 import os
+import re
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +25,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import configs
 from repro.core import quant
+from repro.models import transformer as T
 from repro.runtime import kvcache as kvc
+from repro.runtime.engine import ServingEngine
 
 CFG = configs.get_config("h2o-danube-1.8b")
 PAGE_SIZE = 8          # launch/presets.py SERVE_PRESETS["h2o-danube-1.8b"]
@@ -152,3 +159,48 @@ def test_paged_chunk_attention_compiles_for_v5e(
     _assert_kernel_compiles(step, q, seg, seg,
                             _on(one_chip, _pool(kv_format)), tables,
                             positions)
+
+
+# custom calls of the 2-layer decode step that each kernel job's patterns
+# assign (a scanned layer, so one of each per layer kind): counted on the
+# tree before the step programs named their scopes and the paged-attention
+# kernel took its name, and held here
+JOB_CALLS = {"gemm_w4a16": 7, "paged_attention": 1}
+STEP_SCOPES = ("attn_qkv", "kv_write", "attn_core", "attn_out", "mlp",
+               "head", "sample")
+
+
+def test_decode_step_scopes_and_kernel_jobs_for_v5e(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The engine's danube-width decode step (2 of its 24 layers, fused
+    attention, planned as on one chip) compiled for a v5e: every named
+    scope reaches the ops' metadata, and the benchmark's kernel jobs
+    assign the custom calls they assigned before there were scopes."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(CFG, num_layers=2, w4a16_strategy="auto")
+    params = jax.eval_shape(lambda: T.quantize_params(
+        T.init_params(jax.random.PRNGKey(0), cfg), cfg))
+    eng = ServingEngine(cfg, params, max_batch=SLOTS, max_prompt_len=300,
+                        max_new_tokens=32, page_size=PAGE_SIZE,
+                        prefill_chunk=CHUNK, attn_path="fused")
+    text = eng._serve_step().lower(
+        _on(one_chip, params),
+        _on(one_chip, eng._serve_inputs_abstract())).compile().as_text()
+    names = set(re.findall(r'op_name="[^"]*/(' + "|".join(STEP_SCOPES)
+                           + r')/', text))
+    assert names == set(STEP_SCOPES)
+
+    bench = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                         "chip")
+    sys.path.insert(0, bench)
+    try:
+        from chipbench import jobs
+    finally:
+        sys.path.remove(bench)
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines() if " custom-call(" in line]
+    for name, want in JOB_CALLS.items():
+        job = jobs.load(bench, name)
+        ops = [c for c in calls if job.PATTERN.search(c)
+               and not (job.NOT and job.NOT.search(c))]
+        assert len(ops) == want, (name, ops)
