@@ -119,9 +119,11 @@ def describe(engine: ServingEngine, tag: str) -> None:
     else:
         log(f"{tag}: gemm *: {engine.cfg.w4a16_strategy} (forced)")
     log(f"{tag}: attention decode {engine.attn_path} "
-        f"(kv_partitions={engine.kv_partitions}), prefill "
+        f"(kv_partitions={engine.kv_partitions}, "
+        f"pages_per_block={engine.pages_per_block}), prefill "
         f"{engine.prefill_attn_path} "
-        f"(kv_partitions={engine.prefill_kv_partitions})")
+        f"(kv_partitions={engine.prefill_kv_partitions}, "
+        f"pages_per_block={engine.prefill_pages_per_block})")
 
 
 def tap_logits(engine: ServingEngine):

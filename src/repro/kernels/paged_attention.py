@@ -10,27 +10,41 @@ the single prefill path, so every admit and every speculative verify paid
 that round-trip too. This kernel walks the per-slot block tables *inside*
 the kernel instead, for any query length:
 
-  grid ``(B·Hkv, Q_tiles, S, P)`` — one (slot, kv-head) pair per row of the
-  first axis; ``Q_tiles`` tiles the chunk's queries so each kernel instance
-  holds ``Tq·G ≤ 128`` query rows (``planning.choose_q_block`` — decode's
-  q_len=1 degenerates to the old flash-decoding grid); the slot's
-  ``T = S·P`` table entries are split into ``S`` Split-K style partitions
-  of ``P`` physical pages each (``planning.choose_kv_partitions``, now
-  occupancy-aware of the Q-tile axis — the paper's K ≫ N fix applied to
-  the KV axis).
+  grid ``(B, Q_tiles, S, NJ)`` — one slot per row of the first axis, all
+  of its KV heads in every step; ``Q_tiles`` tiles the chunk's queries so
+  each kernel instance holds ``Tq·G ≤ 128`` query rows
+  (``planning.choose_q_block`` — decode's q_len=1 degenerates to one
+  tile); the slot's ``T`` table entries are split into ``S`` Split-K
+  style partitions (``planning.choose_kv_partitions``, occupancy-aware of
+  the Q-tile axis — the paper's K ≫ N fix applied to the KV axis), each
+  walked in ``NJ`` blocks of ``ppb`` whole pages
+  (``planning.choose_pages_per_block``: a power-of-two divisor of T/S,
+  up to 512 tokens, under a VMEM budget). A table whose page count is
+  not a whole number of blocks is padded with -1 entries.
 
-  block tables ride scalar prefetch (``pltpu.PrefetchScalarGridSpec``), so
-  the K/V BlockSpec index maps resolve ``tables[slot, s·P + p]`` to a
-  *physical page* and the pages stream through VMEM double-buffering — the
-  gathered window never exists in HBM. Per-query positions and the chunk
-  ``start`` arrive as small expanded int32 operands so the kernel reads
-  only its own block.
+  One grid step per (page, head) tile cost about 0.4 us of fixed
+  overhead, and at long contexts that overhead, not HBM bytes, bounded
+  the kernel; a step now covers ``ppb`` pages of ``Hkv`` heads.
+
+  block tables ride scalar prefetch (``pltpu.PrefetchScalarGridSpec``):
+  each of the block's ``ppb`` pages is its own K and its own V operand,
+  whose BlockSpec index map resolves ``tables[slot, (s·NJ + j)·ppb + i]``
+  to a *physical page*, so the pipeline copies one ``(Hkv, ps, D)`` page
+  of every head per DMA and double-buffers the next block behind this
+  one — the gathered window never exists in HBM. (Manual DMAs of
+  ``pool.at[page]`` would do the same, but Mosaic refuses to slice a pool
+  whose ``D`` is not a multiple of 128, such as danube's 80.) Position
+  tags, and the ``kv8_channel`` scales, are gathered through the tables
+  by XLA into one lane row per block (per head for scales); per-query
+  positions and the chunk ``start`` arrive as small expanded int32
+  operands, so the kernel reads only its own blocks.
 
   a :class:`~repro.kernels.template.DensePages` /
-  :class:`~repro.kernels.template.Int8ChannelPages` KV stage produces the
-  in-VMEM (page_size, D) tiles (identity load or per-(token, head) INT8
-  dequant matching ``kv_dequantize`` exactly), and the flash online softmax
-  runs per partition with ``(m, l, acc)`` scratch over all Tq·G rows.
+  :class:`~repro.kernels.template.Int8ChannelPages` KV stage places the
+  per-token scales (none for ``kv_fp16``); per head, the block's pages
+  join as ``(ppb·ps, D)`` rows, one MXU dot gives the ``(Tq·G, ppb·ps)``
+  scores, and the flash online softmax runs per partition with
+  ``(m, l, acc)`` scratch per head over all Tq·G rows.
 
   each partition flushes unnormalized ``(acc, m, l)`` partials; a small
   host-side combine epilogue merges partitions (``exp(m_s - m_max)``
@@ -38,17 +52,17 @@ the kernel instead, for any query length:
   O(B·q_len·Hq·S·D) fp32 bytes instead of a second trip over the window.
 
 Masking is purely positional via the pool's ``page_pos`` tags (``-1`` =
-empty — the null block a ``-1`` table entry resolves to is all ``-1``
-tags) plus the per-row causal / sliding-window / chunk-start clauses, so
-ring-wrap SWA, vision-prefix, shared-prefix and stale-rejected-draft
-semantics carry over from the gather path verbatim: pool entries at
-positions ≥ the chunk start (a sharing peer's copy of this chunk, or a
-rejected draft's leftover tags) are masked in-kernel, the single-counting
-rule the gather path applied by rewriting ``win.pos``. The chunk's own
-K/V — which the caller scatters only *after* attention, preserving the
-gather-before-scatter SWA-wrap ordering — contributes one extra
-"partition" computed as a tiny C×C host einsum and merged in the same
-combine epilogue. Token parity with gather + ``prefix_chunk_attention``
+empty — the null block a ``-1`` or padding table entry resolves to is all
+``-1`` tags) plus the per-row causal / sliding-window / chunk-start
+clauses, so ring-wrap SWA, vision-prefix, shared-prefix and
+stale-rejected-draft semantics carry over from the gather path verbatim:
+pool entries at positions ≥ the chunk start (a sharing peer's copy of
+this chunk, or a rejected draft's leftover tags) are masked in-kernel,
+the single-counting rule the gather path applied by rewriting
+``win.pos``. The chunk's own K/V — which the caller scatters only *after*
+attention, preserving the gather-before-scatter SWA-wrap ordering —
+contributes one extra "partition" computed as a tiny C×C host einsum and
+merged in the same combine epilogue. Token parity with gather + ``prefix_chunk_attention``
 is asserted by tests/test_paged_attention.py.
 
 ``interpret=None`` auto-selects interpret mode on CPU hosts
@@ -89,64 +103,80 @@ def kv_stage_for(pool, fmt: KVFormat):
         k_scale=pool.k_scale, v_scale=pool.v_scale)
 
 
-def _make_kernel(stage, *, P: int, window: int, n_stage: int,
+def _make_kernel(stage, *, Hkv: int, ppb: int, window: int, n_tok: int,
                  compute_dtype):
     def kernel(tbl_ref, q_ref, qpos_ref, spos_ref, *rest):
-        # tbl_ref (B, S*P) is the scalar-prefetch operand driving the
-        # BlockSpec index maps below; qpos/spos are per-row int32 blocks.
-        stage_refs = rest[:n_stage]
-        pp_ref, o_ref, mo_ref, lo_ref, m_ref, l_ref, acc_ref = rest[n_stage:]
-        p = pl.program_id(3)
+        # tbl_ref (B, S·NJ·ppb) is the scalar-prefetched table the page
+        # operands' index maps read; the ppb K and ppb V refs each hold
+        # one (1, Hkv, ps, D) page; tag_ref and the stage's token refs
+        # are lane rows of the block's ppb·ps tokens
+        k_refs, v_refs = rest[:ppb], rest[ppb:2 * ppb]
+        tag_ref = rest[2 * ppb]
+        tok_refs = rest[2 * ppb + 1:2 * ppb + 1 + n_tok]
+        o_ref, mo_ref, lo_ref, m_ref, l_ref, acc_ref = \
+            rest[2 * ppb + 1 + n_tok:]
+        j = pl.program_id(3)
 
-        @pl.when(p == 0)
+        @pl.when(j == 0)
         def _init():
             m_ref[...] = jnp.full_like(m_ref, NEG_INF)
             l_ref[...] = jnp.zeros_like(l_ref)
             acc_ref[...] = jnp.zeros_like(acc_ref)
-
-        q = q_ref[0, 0, 0]                                # (QG, D)
-        k, v = stage.produce(stage_refs, compute_dtype)   # (ps, D) each
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)           # (QG, ps)
 
         # pos-tag masking — identical to prefix_chunk_attention's
         # ``kpos >= 0 & kpos <= qpos`` (+ window), plus ``kpos < start``:
         # the pool copy of anything at/after the chunk start (a peer's
         # duplicate, a rejected draft's stale tags) is masked so only the
         # in-flight segment supplies those positions. The null block's
-        # tags are all -1, so unmapped table entries mask themselves out.
-        kpos = pp_ref[0]                                  # (1, ps)
+        # tags are all -1, so unmapped and padded table entries mask
+        # themselves out.
+        kpos = tag_ref[0, 0]                              # (1, L)
         qe = qpos_ref[0, 0]                               # (QG, 1)
         se = spos_ref[0, 0]
         valid = (kpos >= 0) & (kpos <= qe) & (kpos < se)
         if window:
             valid &= kpos > qe - window
-        s = jnp.where(valid, s, NEG_INF)
 
-        m_prev = m_ref[:, :1]                             # (QG, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        pexp = jnp.exp(s - m_new)                         # (QG, ps)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = jnp.broadcast_to(
-            l_ref[:, :1] * corr + jnp.sum(pexp, axis=-1, keepdims=True),
-            l_ref.shape)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        for h in range(Hkv):
+            k = _rows(k_refs, h, compute_dtype)           # (L, D)
+            v = _rows(v_refs, h, compute_dtype)
+            sc = jax.lax.dot_general(
+                q_ref[0, h, 0], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)       # (QG, L)
+            sc = jnp.where(valid, stage.scores(sc, tok_refs, h), NEG_INF)
 
-        @pl.when(p == P - 1)
+            m_prev = m_ref[h, :, :1]                      # (QG, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            pexp = jnp.exp(sc - m_new)                    # (QG, L)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = jnp.broadcast_to(
+                l_ref[h, :, :1] * corr
+                + jnp.sum(pexp, axis=-1, keepdims=True), l_ref.shape[1:])
+            pv = stage.probs(pexp, tok_refs, h).astype(compute_dtype)
+            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                pv, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+
+        @pl.when(j == pl.num_programs(3) - 1)
         def _flush():
-            o_ref[0, 0, 0, 0] = acc_ref[...]              # unnormalized
-            mo_ref[0, 0, 0, 0] = m_ref[...]
-            lo_ref[0, 0, 0, 0] = l_ref[...]
+            o_ref[0, :, 0, 0] = acc_ref[...]              # unnormalized
+            mo_ref[0, :, 0, 0] = m_ref[...]
+            lo_ref[0, :, 0, 0] = l_ref[...]
 
     return kernel
 
 
+def _rows(page_refs, h, compute_dtype):
+    """Head ``h`` of the block's pages as (ppb·ps, D) rows for the MXU."""
+    tiles = [r[0, h] for r in page_refs]
+    rows = tiles[0] if len(tiles) == 1 else jnp.concatenate(tiles, axis=0)
+    return rows.astype(compute_dtype)
+
+
 def _pooled_partials(qg, positions, start, pool, tables, *, window: int,
-                     fmt: KVFormat, kv_partitions, interpret):
+                     fmt: KVFormat, kv_partitions, pages_per_block,
+                     interpret):
     """Kernel pass over the pooled pages; per-query unnormalized partials.
 
     qg: (B, C, Hkv, G, D) pre-scaled queries in the compute dtype;
@@ -166,11 +196,18 @@ def _pooled_partials(qg, positions, start, pool, tables, *, window: int,
     if kv_partitions is None:
         kv_partitions = planning.choose_kv_partitions(B, Hkv, T, q_tiles=QT)
     S = max(1, min(int(kv_partitions), T))
-    if T % S:
+    if pages_per_block is None:
+        pages_per_block = planning.choose_pages_per_block(
+            T, ps, Hkv, D, pool.k_pool.dtype, QG, S)
+    ppb = max(1, int(pages_per_block))
+    n_blocks = -(-T // ppb)
+    if n_blocks % S:
         raise ValueError(
-            f"kv_partitions={S} must divide the table length T={T} "
-            f"(choose_kv_partitions only returns divisors)")
-    P = T // S
+            f"kv_partitions={S} must divide the table's {n_blocks} blocks "
+            f"of {ppb} pages (T={T}; choose_pages_per_block only returns "
+            f"divisors of T // S)")
+    NJ = n_blocks // S
+    L = ppb * ps
 
     # host-side prep: q rows laid out (qt, tq, g); per-row positions and
     # chunk starts expanded on the host into (QG, 1) columns, so each
@@ -181,59 +218,64 @@ def _pooled_partials(qg, positions, start, pool, tables, *, window: int,
         (B, QT, Tq, G)).reshape(B, QT, QG, 1)
     spos = jnp.broadcast_to(
         start.reshape(B, 1, 1, 1).astype(jnp.int32), (B, QT, QG, 1))
-    bt = jnp.where(tables < 0, 0, tables).astype(jnp.int32)   # NULL_BLOCK=0
+    # -1 entries, and the padding up to whole blocks, read the null block
+    # (NULL_BLOCK=0, all tags -1)
+    bt = jnp.where(tables < 0, 0, tables).astype(jnp.int32)
+    bt = jnp.pad(bt, ((0, 0), (0, n_blocks * ppb - T)))
+
+    # per-token values gathered through the tables into one lane row per
+    # block (tags) or per (block, head) (the stage's scales)
+    tags = pool.page_pos[bt].reshape(B, n_blocks, 1, L)
+
+    def head_rows(x):                                 # (nb, Hkv, ps)
+        y = x[bt].reshape(B, n_blocks, ppb, Hkv, ps)
+        return y.transpose(0, 1, 3, 2, 4).reshape(B, n_blocks, Hkv, L)
 
     stage = kv_stage_for(pool, fmt)
-    operands = stage.operands()
-    n_stage = len(operands)
+    tok = [head_rows(x) for x in stage.token_values()]
 
-    def slot(bh):
-        return bh // Hkv
+    def blk(b, qt, s, j, tbl):
+        return (b, s * NJ + j, 0, 0)
 
-    def head(bh):
-        return bh % Hkv
-
-    def page(bh, s, p, tbl):
-        return tbl[slot(bh), s * P + p]
+    def page_spec(i):
+        # page i of block j: one (1, Hkv, ps, D) DMA of every head
+        def index(b, qt, s, j, tbl):
+            return (tbl[b, (s * NJ + j) * ppb + i], 0, 0, 0)
+        return pl.BlockSpec((1,) + pool.k_pool.shape[1:], index)
 
     def row_block():
         return pl.BlockSpec((1, 1, QG, 1),
-                            lambda bh, qt, s, p, tbl: (slot(bh), qt, 0, 0))
+                            lambda b, qt, s, j, tbl: (b, qt, 0, 0))
 
     in_specs = [
-        pl.BlockSpec((1, 1, 1, QG, D),
-                     lambda bh, qt, s, p, tbl:
-                     (slot(bh), head(bh), qt, 0, 0)),
+        pl.BlockSpec((1, Hkv, 1, QG, D),
+                     lambda b, qt, s, j, tbl: (b, 0, qt, 0, 0)),
         row_block(),
         row_block(),
+        *[page_spec(i) for i in range(ppb)],          # K pages
+        *[page_spec(i) for i in range(ppb)],          # V pages
+        pl.BlockSpec((1, 1, 1, L), blk),
+        *[pl.BlockSpec((1, 1, Hkv, L), blk) for _ in tok],
     ]
-    # stage operands are (nb, Hkv, ps, ·) pools, one (page, head) tile each
-    for shape in stage.block_shapes(ps, D):
-        in_specs.append(pl.BlockSpec(
-            shape, lambda bh, qt, s, p, tbl:
-            (page(bh, s, p, tbl), head(bh), 0, 0)))
-    in_specs.append(pl.BlockSpec(            # page_pos tags as (nb, 1, ps)
-        (1, 1, ps), lambda bh, qt, s, p, tbl: (page(bh, s, p, tbl), 0, 0)))
 
     def part_spec(last):
-        return pl.BlockSpec((1, 1, 1, 1, QG, last),
-                            lambda bh, qt, s, p, tbl:
-                            (slot(bh), head(bh), qt, s, 0, 0))
+        return pl.BlockSpec((1, Hkv, 1, 1, QG, last),
+                            lambda b, qt, s, j, tbl: (b, 0, qt, s, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B * Hkv, QT, S, P),
+        grid=(B, QT, S, NJ),
         in_specs=in_specs,
         out_specs=[part_spec(D), part_spec(LANES), part_spec(LANES)],
         scratch_shapes=[
-            pltpu.VMEM((QG, LANES), jnp.float32),     # running max
-            pltpu.VMEM((QG, LANES), jnp.float32),     # running denom
-            pltpu.VMEM((QG, D), jnp.float32),         # unnormalized acc
+            pltpu.VMEM((Hkv, QG, LANES), jnp.float32),    # running max
+            pltpu.VMEM((Hkv, QG, LANES), jnp.float32),    # running denom
+            pltpu.VMEM((Hkv, QG, D), jnp.float32),        # unnormalized acc
         ],
     )
     o_part, m_part, l_part = pl.pallas_call(
-        _make_kernel(stage, P=P, window=window, n_stage=n_stage,
-                     compute_dtype=qk.dtype),
+        _make_kernel(stage, Hkv=Hkv, ppb=ppb, window=window,
+                     n_tok=len(tok), compute_dtype=qk.dtype),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, Hkv, QT, S, QG, D), jnp.float32),
@@ -244,7 +286,8 @@ def _pooled_partials(qg, positions, start, pool, tables, *, window: int,
             ("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="paged_attention",
-    )(bt, qk, qpos, spos, *operands, pool.page_pos[:, None, :])
+    )(bt, qk, qpos, spos, *[stage.k_pool] * ppb, *[stage.v_pool] * ppb,
+      tags, *tok)
 
     def per_query(x):
         # (B, Hkv, QT, S, QG, ·) → (B, Hkv, C, S, G, ·): split QG = Tq·G
@@ -279,6 +322,7 @@ def fused_paged_attention(
     fmt: KVFormat,
     out_dtype,
     kv_partitions: Optional[int] = None,
+    pages_per_block: Optional[int] = None,
     interpret=None,
 ) -> jax.Array:
     """One-pass paged decode attention; drop-in for ``gather_window`` +
@@ -290,8 +334,9 @@ def fused_paged_attention(
     collapse onto the decode mask ``kpos <= pos`` exactly.
 
     ``kv_partitions`` is the Split-K degree over the page axis (None →
-    ``planning.choose_kv_partitions``); ``interpret=None`` auto-selects
-    interpret mode on CPU.
+    ``planning.choose_kv_partitions``), ``pages_per_block`` the table
+    pages one grid step covers (None → ``planning.choose_pages_per_block``);
+    ``interpret=None`` auto-selects interpret mode on CPU.
     """
     interpret = common.resolve_interpret(interpret)
     B, Hq, D = q.shape
@@ -305,7 +350,8 @@ def fused_paged_attention(
     pos = pos.astype(jnp.int32)
     acc, m, l = _pooled_partials(
         qg, pos[:, None], pos + 1, pool, tables, window=window, fmt=fmt,
-        kv_partitions=kv_partitions, interpret=interpret)
+        kv_partitions=kv_partitions, pages_per_block=pages_per_block,
+        interpret=interpret)
     out = _combine(acc, m, l)                          # (B, Hkv, 1, G, D)
     return out[:, :, 0].reshape(B, Hq, D).astype(q.dtype)
 
@@ -322,6 +368,7 @@ def fused_chunk_attention(
     fmt: KVFormat,
     out_dtype,
     kv_partitions: Optional[int] = None,
+    pages_per_block: Optional[int] = None,
     interpret=None,
 ) -> jax.Array:
     """One-pass paged attention for a (B, C) chunk — chunked prefill
@@ -349,7 +396,8 @@ def fused_chunk_attention(
     positions = positions.astype(jnp.int32)
     acc, m, l = _pooled_partials(
         qg, positions, positions[:, 0], pool, tables, window=window,
-        fmt=fmt, kv_partitions=kv_partitions, interpret=interpret)
+        fmt=fmt, kv_partitions=kv_partitions,
+        pages_per_block=pages_per_block, interpret=interpret)
 
     # intra-chunk partial: prefix_chunk_attention's mask and dtype policy
     # over the segment alone (fp32 scores, p cast to the V dtype)

@@ -72,6 +72,7 @@ __all__ = [
     "choose_split_k", "num_cores",
     "AttentionProblem", "AttentionPlan", "register_attn_path",
     "available_attn_paths", "plan_attention", "choose_kv_partitions",
+    "choose_pages_per_block",
 ]
 
 
@@ -897,6 +898,7 @@ class AttentionProblem:
 class AttentionPlan:
     path: str                     # "ring" | "gather" | "fused"
     kv_partitions: int = 1        # Split-K degree over the page axis
+    pages_per_block: int = 1      # table pages per fused-kernel grid step
 
 
 @dataclasses.dataclass(frozen=True)
@@ -936,6 +938,54 @@ def choose_kv_partitions(B: int, Hkv: int, pages: int, *,
     while s * 2 <= want and pages % (s * 2) == 0:
         s *= 2
     return s
+
+
+# the fused attention kernel's page blocks: tokens one grid step aims for,
+# and the VMEM its double-buffered K/V pages and per-head temporaries may
+# take (half of common.VMEM_BUDGET, leaving the pipelined q, tag and
+# partial blocks room)
+ATTN_BLOCK_TOKENS = 512
+ATTN_VMEM_BUDGET = 8 * 1024 * 1024
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def choose_pages_per_block(T: int, ps: int, Hkv: int, D: int, kv_dtype,
+                           QG: int, S: int = 1) -> int:
+    """Table pages the fused paged-attention kernel covers per grid step.
+
+    A grid step costs a fixed overhead on top of its DMAs, so a step over
+    one (page, head) tile is bound by that overhead, not by bytes; one
+    step over ``ppb`` whole pages of every head amortizes it. ``ppb`` is
+    the largest power-of-two divisor of the partition's ``T // S`` pages
+    with at most :data:`ATTN_BLOCK_TOKENS` tokens whose VMEM fits
+    :data:`ATTN_VMEM_BUDGET`: K and V pages double-buffered, each
+    ``(ps, D)`` head tile padded to its VMEM tile — 128 lanes, and rows
+    to 8 or to the dtype's packed tile (16 for bf16, 32 for int8),
+    whichever ``ps`` reaches (a v5e tiles a bf16 page of 8 rows (8, 128))
+    — plus each head's ``(ppb·ps, D)`` K/V rows and ``(QG, ppb·ps)``
+    scores, counted at 4 bytes. Shapes only.
+    """
+    itemsize = jnp.dtype(kv_dtype).itemsize
+    sublanes = min(8 * max(1, 4 // itemsize), _round_up(ps, 8))
+    page = Hkv * _round_up(ps, sublanes) * _round_up(D, common.LANE) \
+        * itemsize
+
+    def vmem(ppb: int) -> int:
+        L = ppb * ps
+        return (4 * ppb * page                               # K, V × 2 bufs
+                + 4 * L * _round_up(D, common.LANE) * 4      # K/V rows
+                + 4 * _round_up(QG, 8) * L * 4)              # scores, probs
+
+    per_part = max(1, T // max(1, S))
+    ppb = 1
+    while (per_part % (2 * ppb) == 0
+           and 2 * ppb * ps <= ATTN_BLOCK_TOKENS
+           and vmem(2 * ppb) <= ATTN_VMEM_BUDGET):
+        ppb *= 2
+    return ppb
 
 
 def choose_q_block(q_len: int, group: int, *, target: int = 128) -> int:
@@ -995,10 +1045,11 @@ register_attn_path("fused", cost=_cost_attn_fused,
 
 
 def _attn_plan_for(problem: AttentionProblem, name: str) -> AttentionPlan:
-    parts = 1
+    parts = ppb = 1
     if name == "fused":
         group = max(1, problem.Hq // max(1, problem.Hkv))
-        q_tiles = problem.q_len // choose_q_block(problem.q_len, group)
+        tq = choose_q_block(problem.q_len, group)
+        q_tiles = problem.q_len // tq
         parts = choose_kv_partitions(problem.B, problem.Hkv, problem.pages,
                                      q_tiles=q_tiles)
         # every partition flushes O(q_len·Hq·D) unnormalized partials, so
@@ -1009,7 +1060,13 @@ def _attn_plan_for(problem: AttentionProblem, name: str) -> AttentionPlan:
         # q_len=1 never hits it)
         while parts > 1 and parts * problem.q_len * 2 > problem.ctx:
             parts //= 2
-    return AttentionPlan(path=name, kv_partitions=parts)
+        kv_dtype = (jnp.int8 if _attn_quantized(problem)
+                    else jnp.bfloat16 if problem.act_bytes == 2
+                    else jnp.float32)
+        ppb = choose_pages_per_block(problem.pages, problem.page_size,
+                                     problem.Hkv, problem.D, kv_dtype,
+                                     tq * group, parts)
+    return AttentionPlan(path=name, kv_partitions=parts, pages_per_block=ppb)
 
 
 def plan_attention(problem: AttentionProblem, *,

@@ -267,19 +267,21 @@ class GroupedInt4Raw:
 # ---------------------------------------------------------------------------
 # KV stages (the stage vocabulary extended from GEMM to attention).
 #
-# A KVStage is the attention analogue of a WeightStage: it declares the
-# paged-pool operands the fused decode kernel walks (runtime/kvcache.py
-# block pools, one physical page per grid step), how each operand is
-# blocked, and how the in-VMEM (page_size, D) K/V tiles are produced —
-# identity load for ``kv_fp16`` pages, per-(token, head) INT8 dequant for
+# A KVStage is the attention analogue of a WeightStage: it names the paged
+# pools the fused attention kernel copies page by page (runtime/kvcache.py
+# block pools, ``(num_blocks, Hkv, page_size, D)``, so one page of every
+# head is one contiguous DMA), the per-(token, head) values that ride
+# beside them, and where those values enter the online softmax: nowhere
+# for ``kv_fp16`` pages; as the per-(token, head) INT8 scales for
 # ``kv8_channel`` (the same AIV dequant role the GEMM weight stages play,
 # fused into the consumer instead of round-tripping through HBM).
 #
-# Every operand is blocked as ``(1, 1, page_size, ·)`` over a
-# ``(num_blocks, Hkv, page_size, ·)`` array and indexed ``(page, head, 0,
-# 0)``: the last two block dims are whole axes, as the TPU's (8, 128)
-# tiling rule requires. The emitter (kernels/paged_attention.py) turns that
-# into block-table index maps over the scalar-prefetched tables.
+# Per-token values reach the kernel as ``(Hkv, ppb·page_size)`` blocks: one
+# lane row per head, one lane per token of the block. The emitter
+# (kernels/paged_attention.py) gathers them through the block tables with
+# XLA, because a page's ``(Hkv, page_size)`` values cannot be copied into
+# a slice of a lane row. A stage applies them to the fp32 scores and
+# probabilities of head ``h``, so payload tiles reach the MXU as stored.
 # ---------------------------------------------------------------------------
 
 
@@ -291,26 +293,25 @@ class DensePages:
     k_pool: jax.Array                 # (num_blocks, Hkv, ps, D)
     v_pool: jax.Array
 
-    def operands(self) -> List[jax.Array]:
-        return [self.k_pool, self.v_pool]
+    def token_values(self) -> List[jax.Array]:
+        return []
 
-    def block_shapes(self, ps: int, D: int) -> List[Tuple[int, ...]]:
-        return [(1, 1, ps, D), (1, 1, ps, D)]
+    def scores(self, s, refs: Sequence, h: int):
+        return s
 
-    def produce(self, refs: Sequence, compute_dtype):
-        k_ref, v_ref = refs
-        return (k_ref[0, 0].astype(compute_dtype),
-                v_ref[0, 0].astype(compute_dtype))
+    def probs(self, p, refs: Sequence, h: int):
+        return p
 
 
 @dataclasses.dataclass(frozen=True)
 class Int8ChannelPages:
     """Per-(token, head) INT8 KV dequant in VMEM (``kv8_channel``).
 
-    Matches ``core/quant.kv_dequantize`` bit-for-bit: fp32 payload × fp32
-    scale, cast to the cache compute dtype — the dequantized page never
-    exists outside VMEM (vs. the gather path, which materializes the whole
-    dequantized window to HBM before attention reads it back).
+    ``core/quant.kv_dequantize`` scales each payload row by its token's
+    fp32 scale; here the K scale multiplies the fp32 score column and the
+    V scale the fp32 probability column instead, which is the same sum of
+    the same products (exact up to fp32 rounding). The dequantized page
+    never exists, in HBM or in VMEM.
     """
 
     k_pool: jax.Array                 # (num_blocks, Hkv, ps, D) int8
@@ -318,22 +319,14 @@ class Int8ChannelPages:
     k_scale: jax.Array                # (num_blocks, Hkv, ps) fp32
     v_scale: jax.Array
 
-    def operands(self) -> List[jax.Array]:
-        # scales get a unit lane axis: a (ps, 1) column per (page, head)
-        return [self.k_pool, self.v_pool,
-                self.k_scale[..., None], self.v_scale[..., None]]
+    def token_values(self) -> List[jax.Array]:
+        return [self.k_scale, self.v_scale]
 
-    def block_shapes(self, ps: int, D: int) -> List[Tuple[int, ...]]:
-        return [(1, 1, ps, D), (1, 1, ps, D), (1, 1, ps, 1), (1, 1, ps, 1)]
+    def scores(self, s, refs: Sequence, h: int):
+        return s * refs[0][0, 0, h:h + 1]          # (QG, L) × (1, L)
 
-    def produce(self, refs: Sequence, compute_dtype):
-        k_ref, v_ref, ks_ref, vs_ref = refs
-
-        def deq(p_ref, s_ref):
-            q = p_ref[0, 0].astype(jnp.float32)             # (ps, D)
-            return (q * s_ref[0, 0]).astype(compute_dtype)  # × (ps, 1)
-
-        return deq(k_ref, ks_ref), deq(v_ref, vs_ref)
+    def probs(self, p, refs: Sequence, h: int):
+        return p * refs[1][0, 0, h:h + 1]
 
 
 # ---------------------------------------------------------------------------

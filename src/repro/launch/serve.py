@@ -303,7 +303,8 @@ def main(argv=None):
                  if engine.alloc is not None and engine.alloc.warm_bytes
                  else ""))
         print(f"[serve] attn path: {engine.attn_path}"
-              + (f" (kv_partitions={engine.kv_partitions})"
+              + (f" (kv_partitions={engine.kv_partitions}, "
+                 f"pages_per_block={engine.pages_per_block})"
                  if engine.attn_path == "fused" else "")
               + ("" if args.attn_path else " [planned]"))
     for lk, plan in sorted(engine.plans.items()):

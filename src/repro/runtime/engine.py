@@ -364,6 +364,7 @@ class ServingEngine:
         attn_plan = planning.plan_attention(attn_problem, path=forced_path)
         self.attn_path = attn_plan.path
         self.kv_partitions = attn_plan.kv_partitions
+        self.pages_per_block = attn_plan.pages_per_block
         # chunked prefill is a *different* attention problem than decode —
         # q_len = the prefill chunk, one slot per call — so it gets its
         # own costed plan (the multi-query fused kernel serves q_len > 1;
@@ -376,9 +377,11 @@ class ServingEngine:
                 path=forced_path)
             self.prefill_attn_path = pf_plan.path
             self.prefill_kv_partitions = pf_plan.kv_partitions
+            self.prefill_pages_per_block = pf_plan.pages_per_block
         else:
             self.prefill_attn_path = self.attn_path
             self.prefill_kv_partitions = self.kv_partitions
+            self.prefill_pages_per_block = self.pages_per_block
 
         self.spec_k = int(spec_k)
         self.proposer: Optional[spec.Proposer] = None
@@ -400,9 +403,11 @@ class ServingEngine:
                 path=forced_path)
             self.verify_attn_path = vf_plan.path
             self.verify_kv_partitions = vf_plan.kv_partitions
+            self.verify_pages_per_block = vf_plan.pages_per_block
         else:
             self.verify_attn_path = self.attn_path
             self.verify_kv_partitions = self.kv_partitions
+            self.verify_pages_per_block = self.pages_per_block
 
         self.plans: Dict[str, planning.KernelPlan] = {}
         if (getattr(cfg, "w4a16_strategy", "auto") == "auto"
@@ -1313,23 +1318,28 @@ class ServingEngine:
 
     def _path_gauges(self) -> None:
         """The planner's attention paths, fixed for the engine's life,
-        surfaced on GET /metrics: 0=ring, 1=gather, 2=fused."""
+        surfaced on GET /metrics: 0=ring, 1=gather, 2=fused; beside each,
+        the table pages one grid step of the fused kernel covers (1 on
+        the XLA paths)."""
         m = self.metrics
         if m is None:
             return
-        m.gauge("engine_attn_path",
-                "decode attention path (0=ring 1=gather 2=fused)").set(
-            _PATH_CODE.get(self.attn_path, -1))
+        regimes = [("", "decode", self.attn_path, self.pages_per_block)]
         if self.chunked:
-            m.gauge("engine_prefill_attn_path",
-                    "chunked-prefill attention path "
-                    "(0=ring 1=gather 2=fused)").set(
-                _PATH_CODE.get(self.prefill_attn_path, -1))
+            regimes.append(("prefill_", "chunked-prefill",
+                            self.prefill_attn_path,
+                            self.prefill_pages_per_block))
         if self.proposer is not None:
-            m.gauge("engine_verify_attn_path",
-                    "speculative-verify attention path "
-                    "(0=ring 1=gather 2=fused)").set(
-                _PATH_CODE.get(self.verify_attn_path, -1))
+            regimes.append(("verify_", "speculative-verify",
+                            self.verify_attn_path,
+                            self.verify_pages_per_block))
+        for prefix, what, path, ppb in regimes:
+            m.gauge(f"engine_{prefix}attn_path",
+                    f"{what} attention path (0=ring 1=gather 2=fused)").set(
+                _PATH_CODE.get(path, -1))
+            m.gauge(f"engine_{prefix}attn_pages_per_block",
+                    f"{what} attention: table pages per fused-kernel "
+                    f"grid step").set(ppb)
 
     def step(self, *, verbose: bool = False) -> StepEvents:
         """One scheduler iteration: admit arrived requests into free slots,

@@ -319,10 +319,11 @@ def paged_decode_attention(q: jax.Array, pool: PagedKVCache,
     over the KV working set; ``live_pages`` (static) clamps that gather
     to the batch's live-page high-water mark (see ``gather_window``).
     ``"fused"`` walks the block table inside the Pallas kernel
-    (``kernels/paged_attention.py``): pages stream through VMEM,
-    `kv8_channel` dequant and online softmax fuse into one pass, and the
-    clamp is moot — unwritten pages cost one masked VMEM tile, not an
-    HBM materialization. Both are token-identical;
+    (``kernels/paged_attention.py``): blocks of whole pages stream
+    through VMEM, `kv8_channel` dequant and online softmax fuse into one
+    pass, and the clamp is moot — an unwritten page costs its share of a
+    block's DMAs and masked scores, not an HBM materialization. Both are
+    token-identical;
     ``planning.plan_attention`` picks per backend (gather on CPU, fused
     on TPU for long contexts).
     """

@@ -60,16 +60,32 @@ def _filled_pool(fmt_name, *, B=2, Hkv=2, D=32, ps=4, T_pages=4, fill=14,
     return q, pool, tables, pos, fmt
 
 
+# page blocks: (kv_partitions, pages_per_block, table pages) — one page
+# per step, a block inside the partition, the whole partition, S=2 with
+# blocks, and ppb=3 over 4 pages (the table padded with -1 up to 6)
+BLOCKS = [
+    pytest.param((1, 1, 8), id="S1-ppb1"),
+    pytest.param((1, 4, 8), id="S1-ppb4"),
+    pytest.param((1, 8, 8), id="S1-whole"),
+    pytest.param((2, 2, 8), id="S2-ppb2"),
+    pytest.param((2, 4, 8), id="S2-whole"),
+    pytest.param((1, 3, 4), id="S1-ppb3-padded"),
+]
+
+
 @pytest.mark.parametrize("fmt_name", ["kv_fp16", "kv8_channel"])
 @pytest.mark.parametrize("window", [0, 8])
-@pytest.mark.parametrize("parts", [1, 2, 4])
+@pytest.mark.parametrize("parts", [1, 2, 4] + BLOCKS)
 def test_fused_matches_gather(fmt_name, window, parts):
-    q, pool, tables, pos, fmt = _filled_pool(fmt_name)
+    parts, ppb, T_pages = parts if isinstance(parts, tuple) \
+        else (parts, None, 4)
+    q, pool, tables, pos, fmt = _filled_pool(fmt_name, T_pages=T_pages,
+                                             fill=3 * T_pages + 2)
     ref = kvc.paged_decode_attention(q, pool, tables, pos, window=window,
                                      fmt=fmt, out_dtype=jnp.float32)
     out = fused_paged_attention(q, pool, tables, pos, window=window,
                                 fmt=fmt, out_dtype=jnp.float32,
-                                kv_partitions=parts)
+                                kv_partitions=parts, pages_per_block=ppb)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
 
@@ -83,10 +99,12 @@ def test_fused_matches_gather_wrapped_ring():
         ref = kvc.paged_decode_attention(q, pool, tables, pos,
                                          window=window, fmt=fmt,
                                          out_dtype=jnp.float32)
-        out = fused_paged_attention(q, pool, tables, pos, window=window,
-                                    fmt=fmt, out_dtype=jnp.float32)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-6)
+        for ppb in (None, 1, 2, 4):         # blocks of the wrapped ring
+            out = fused_paged_attention(q, pool, tables, pos, window=window,
+                                        fmt=fmt, out_dtype=jnp.float32,
+                                        pages_per_block=ppb)
+            np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                       rtol=2e-5, atol=2e-6)
 
 
 def test_fused_unmapped_tables_mask_to_null_block():
@@ -102,11 +120,42 @@ def test_fused_unmapped_tables_mask_to_null_block():
                                rtol=2e-5, atol=2e-6)
 
 
+def test_fused_null_only_block():
+    """A block made only of -1 entries (slot 1's second block of two) is
+    all null-block tags: it contributes nothing, the slot's first block
+    carries its whole window."""
+    q, pool, tables, pos, fmt = _filled_pool("kv8_channel", T_pages=8,
+                                             fill=14)
+    tables = tables.at[1, 4:].set(-1)
+    ref = kvc.paged_decode_attention(q, pool, tables, pos, fmt=fmt,
+                                     out_dtype=jnp.float32)
+    for parts, ppb in ((1, 4), (2, 4), (2, 2)):
+        out = fused_paged_attention(q, pool, tables, pos, fmt=fmt,
+                                    out_dtype=jnp.float32,
+                                    kv_partitions=parts, pages_per_block=ppb)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-5, atol=2e-6)
+
+
 def test_fused_partition_count_validation():
+    """S must divide the table's block count T / ppb (T padded up to
+    whole blocks): 4 pages in blocks of 1 do not split 3 ways, nor 2
+    blocks of 2 four ways; 2 blocks of 3 (padded) split 2 ways."""
     q, pool, tables, pos, fmt = _filled_pool("kv_fp16")   # T=4 pages
     with pytest.raises(ValueError, match="must divide"):
         fused_paged_attention(q, pool, tables, pos, fmt=fmt,
                               out_dtype=jnp.float32, kv_partitions=3)
+    with pytest.raises(ValueError, match="must divide"):
+        fused_paged_attention(q, pool, tables, pos, fmt=fmt,
+                              out_dtype=jnp.float32, kv_partitions=4,
+                              pages_per_block=2)
+    ref = kvc.paged_decode_attention(q, pool, tables, pos, fmt=fmt,
+                                     out_dtype=jnp.float32)
+    out = fused_paged_attention(q, pool, tables, pos, fmt=fmt,
+                                out_dtype=jnp.float32, kv_partitions=2,
+                                pages_per_block=3)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
 
 
 def test_fused_interpret_toggle():
@@ -193,12 +242,13 @@ def _chunk_reference(q, kseg, vseg, pool, tables, positions, *, window,
 
 
 @pytest.mark.parametrize("fmt_name", ["kv_fp16", "kv8_channel"])
-@pytest.mark.parametrize("C,start", [(1, 6), (3, 6), (6, 5)])
+@pytest.mark.parametrize("C,start", [(1, 6), (3, 6), (6, 5), (32, 6)])
 @pytest.mark.parametrize("window", [0, 8])
 def test_fused_chunk_matches_gather(fmt_name, C, start, window):
-    """The tentpole parity matrix: q_len ∈ {1, 3, page-straddling 6},
-    both KV formats, full + sliding-window masks — the fused multi-query
-    walk must reproduce the gathered-window reference bit-for-policy."""
+    """The tentpole parity matrix: q_len ∈ {1, 3, page-straddling 6, a
+    32-token prefill chunk}, both KV formats, full + sliding-window masks
+    — the fused multi-query walk must reproduce the gathered-window
+    reference bit-for-policy."""
     q, ks, vs, pool, tables, positions, fmt = _chunk_setup(
         fmt_name, C=C, start=start)
     ref = _chunk_reference(q, ks, vs, pool, tables, positions,
@@ -210,15 +260,18 @@ def test_fused_chunk_matches_gather(fmt_name, C, start, window):
                                rtol=2e-5, atol=2e-6)
 
 
-@pytest.mark.parametrize("parts", [1, 2])
+@pytest.mark.parametrize("parts", [1, 2] + [
+    pytest.param((1, 1), id="S1-ppb1"), pytest.param((1, 4), id="S1-whole"),
+    pytest.param((2, 2), id="S2-whole"), pytest.param((1, 3), id="padded")])
 def test_fused_chunk_split_k(parts):
+    parts, ppb = parts if isinstance(parts, tuple) else (parts, None)
     q, ks, vs, pool, tables, positions, fmt = _chunk_setup(
         "kv8_channel", C=3, start=9)
     ref = _chunk_reference(q, ks, vs, pool, tables, positions,
                            window=0, fmt=fmt)
     out = fused_chunk_attention(q, ks, vs, pool, tables, positions,
                                 window=0, fmt=fmt, out_dtype=jnp.float32,
-                                kv_partitions=parts)
+                                kv_partitions=parts, pages_per_block=ppb)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-6)
 
@@ -399,6 +452,42 @@ def test_choose_kv_partitions_q_tiles_occupancy(monkeypatch):
     monkeypatch.setattr(planning, "num_cores", lambda: cores)
     assert planning.choose_kv_partitions(1, 1, 64, q_tiles=cores) == 1
     assert planning.choose_kv_partitions(1, 1, 64, q_tiles=1) > 1
+
+
+def test_choose_pages_per_block():
+    """Page blocks come from shapes alone: a power-of-two divisor of the
+    partition's pages, at most 512 tokens, K/V double-buffered with
+    padded tiles under the VMEM budget."""
+    cell = dict(T=512, ps=8, Hkv=8, D=80, kv_dtype=jnp.bfloat16, QG=4)
+    ppb = planning.choose_pages_per_block(**cell)
+    assert ppb == planning.choose_pages_per_block(**cell)   # pure
+    assert 512 % ppb == 0 and ppb & (ppb - 1) == 0
+    assert 256 <= ppb * 8 <= planning.ATTN_BLOCK_TOKENS
+    # bf16 (8, 80) head tiles pad to (8, 128): 2 KiB, x8 heads, K and V
+    # double-buffered
+    assert 4 * ppb * 8 * 8 * 128 * 2 <= planning.ATTN_VMEM_BUDGET
+    # pages of 32 rows tile (16, 128) in bf16, (32, 128) in int8: the
+    # budget, not the token cap, binds a bf16 block of 16 wide heads
+    big = dict(cell, ps=32, Hkv=16, D=128)
+    b16 = planning.choose_pages_per_block(**big)
+    assert 4 * b16 * 16 * 32 * 128 * 2 <= planning.ATTN_VMEM_BUDGET
+    assert b16 * 32 < planning.ATTN_BLOCK_TOKENS
+    for S in (1, 2, 4, 8):
+        p = planning.choose_pages_per_block(**dict(cell, S=S))
+        assert (512 // S) % p == 0
+    # odd and tiny tables: the divisor rule holds down to one page
+    assert planning.choose_pages_per_block(**dict(cell, T=7)) == 1
+    assert planning.choose_pages_per_block(**dict(cell, T=12)) == 4
+    # wide heads shrink the block to stay under the budget
+    wide = planning.choose_pages_per_block(**dict(cell, Hkv=64, D=128))
+    assert wide < ppb
+    assert 4 * wide * 64 * 8 * 128 * 2 <= planning.ATTN_VMEM_BUDGET
+    # the plan carries what the kernel derives from the same shapes
+    plan = planning.plan_attention(
+        _problem(Hq=32, Hkv=8, D=80, page_size=8, kv_format="kv_fp16"),
+        path="fused")
+    assert plan.pages_per_block == planning.choose_pages_per_block(
+        4096 // 8, 8, 8, 80, jnp.bfloat16, 4, plan.kv_partitions)
 
 
 def test_choose_q_block():
@@ -616,6 +705,8 @@ def test_engine_multi_query_path_metrics():
     text = eng.metrics.render()
     assert "engine_prefill_attn_path" in text
     assert "engine_verify_attn_path" in text
+    assert "engine_verify_attn_pages_per_block " \
+        f"{eng.verify_pages_per_block}" in text
 
 
 def test_engine_attn_path_resolution_and_metrics():
@@ -635,6 +726,15 @@ def test_engine_attn_path_resolution_and_metrics():
     assert f"engine_attn_path {float(1 if eng.attn_path == 'gather' else 2)}" \
         in text.replace(".0", "") or "engine_attn_path" in text
     assert f"engine_attn_path_steps_{eng.attn_path}" in text
+    # pages per fused-kernel grid step, per regime, as planned
+    assert f"engine_attn_pages_per_block {eng.pages_per_block}" in text
+    assert "engine_prefill_attn_pages_per_block " \
+        f"{eng.prefill_pages_per_block}" in text
+    fused = ServingEngine(cfg, params, max_batch=2, max_prompt_len=8,
+                          max_new_tokens=3, page_size=4, attn_path="fused")
+    assert fused.pages_per_block == planning.choose_pages_per_block(
+        fused.pages_slot, 4, cfg.num_kv_heads, cfg.head_dim, cfg.dtype,
+        cfg.num_heads // cfg.num_kv_heads, fused.kv_partitions)
     with pytest.raises(ValueError, match="does not support"):
         ServingEngine(cfg, params, max_batch=2, max_prompt_len=8,
                       max_new_tokens=3, paged=False, attn_path="fused")

@@ -161,6 +161,48 @@ def test_paged_chunk_attention_compiles_for_v5e(
                             positions)
 
 
+# danube.long's decode: 4 slots past the 4096 window, T = 4096 / 8 pages
+CELL_SLOTS, CELL_PAGES = 4, 512
+
+
+@pytest.mark.parametrize("regime", ["decode", "chunk"])
+@pytest.mark.parametrize("kv_format", ["kv_fp16", "kv8_channel"])
+def test_paged_attention_cell_shapes_compile_for_v5e(
+        one_chip, no_persistent_cache, kv_format, regime):
+    """The fused kernel at the benchmark cell's shapes (B 4, T 512, pages
+    of 8, GQA 32/8 x 80): decode at the planned page blocks, and one
+    slot's 32-token chunk over the same table."""
+    from repro.kernels.paged_attention import (fused_chunk_attention,
+                                               fused_paged_attention)
+    fmt = quant.get_kv_format(kv_format)
+    Hq, Hkv, D = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim
+    pool = _on(one_chip, jax.eval_shape(lambda: kvc.init_pool(
+        1 + CELL_SLOTS * CELL_PAGES, PAGE_SIZE, Hkv, D, jnp.bfloat16,
+        kv_format=kv_format)))
+    B = CELL_SLOTS if regime == "decode" else 1
+    tables = jax.ShapeDtypeStruct((B, CELL_PAGES), jnp.int32,
+                                  sharding=one_chip)
+    kw = dict(window=CFG.sliding_window, fmt=fmt, out_dtype=jnp.bfloat16,
+              interpret=False)
+    if regime == "decode":
+        q = jax.ShapeDtypeStruct((B, Hq, D), jnp.bfloat16, sharding=one_chip)
+        pos = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)
+        _assert_kernel_compiles(
+            lambda q, pool, tables, pos: fused_paged_attention(
+                q, pool, tables, pos, **kw), q, pool, tables, pos)
+        return
+    q = jax.ShapeDtypeStruct((1, CHUNK, Hq, D), jnp.bfloat16,
+                             sharding=one_chip)
+    seg = jax.ShapeDtypeStruct((1, CHUNK, Hkv, D), jnp.bfloat16,
+                               sharding=one_chip)
+    positions = jax.ShapeDtypeStruct((1, CHUNK), jnp.int32,
+                                     sharding=one_chip)
+    _assert_kernel_compiles(
+        lambda q, k, v, pool, tables, p: fused_chunk_attention(
+            q, k, v, pool, tables, p, **kw),
+        q, seg, seg, pool, tables, positions)
+
+
 # custom calls of the 2-layer decode step that each kernel job's patterns
 # assign (a scanned layer, so one of each per layer kind): counted on the
 # tree before the step programs named their scopes and the paged-attention
