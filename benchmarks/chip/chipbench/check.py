@@ -21,11 +21,12 @@ Numbers compared, each beside its limit, as far as the cell's
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import os
 from typing import Dict, List
 
 import numpy as np
+
+from chipbench import arch
 
 
 @dataclasses.dataclass
@@ -39,11 +40,8 @@ class Verdict:
 
 
 def load_reference(bench_dir: str, name: str):
-    path = os.path.join(bench_dir, "configs", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"reference_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return arch.load_file(os.path.join(bench_dir, "configs", name + ".py"),
+                          f"reference_{name}")
 
 
 def sample(served: Dict[int, tuple], seed: int, min_tokens: int,

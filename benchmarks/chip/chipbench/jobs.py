@@ -1,19 +1,18 @@
 """Kernel jobs, one file each under ``jobs/``: which device ops do the job
 (``MATCH``, a pattern searched in an op's name and metadata, less those
-matching ``EXCLUDE``) and the least time its work needs
+matching ``EXCLUDE`` where a job has one) and the least time its work needs
 (``least_time(cfgj, steps, peaks)``)."""
 from __future__ import annotations
 
-import importlib.util
 import os
 import re
 
+from chipbench import arch
+
 
 def load(bench_dir: str, name: str):
-    path = os.path.join(bench_dir, "jobs", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"job_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    mod = arch.load_file(os.path.join(bench_dir, "jobs", name + ".py"),
+                         f"job_{name}")
     mod.PATTERN = re.compile(mod.MATCH)
     mod.NOT = re.compile(mod.EXCLUDE) if getattr(mod, "EXCLUDE", None) \
         else None

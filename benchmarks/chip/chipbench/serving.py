@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import traffic as traffic_mod
+from chipbench import arch, traffic as traffic_mod
 
 clock = time.perf_counter
 
@@ -87,32 +87,13 @@ def compile_counter() -> CompileCounter:
     return _COUNTER[0]
 
 
-def program_config(cfgj: dict, quant_format: str):
-    """The program's ModelConfig for ``cfgj``: its named configuration with
-    every size taken from the benchmark's file."""
-    from repro import configs
-
-    cfg = configs.get_config(cfgj["program_config"])
-    return dataclasses.replace(
-        cfg, num_layers=cfgj["num_hidden_layers"],
-        d_model=cfgj["hidden_size"], d_ff=cfgj["intermediate_size"],
-        num_heads=cfgj["num_attention_heads"],
-        num_kv_heads=cfgj["num_key_value_heads"],
-        head_dim=cfgj["head_dim"], vocab_size=cfgj["vocab_size"],
-        sliding_window=cfgj["sliding_window"],
-        rope_theta=cfgj["rope_theta"],
-        mlp_type="swiglu",
-        norm_type="rmsnorm", tie_embeddings=False, dtype=jnp.bfloat16,
-        quant_format=quant_format, w4a16_strategy="auto")
-
-
 def build_engine(cfgj: dict, traffic: dict, params, quant_format: str):
     """A ServingEngine with the configuration's serving settings, sized for
     the traffic's longest prompt and output."""
     from repro.launch.presets import serve_settings_for
     from repro.runtime.engine import ServingEngine
 
-    cfg = program_config(cfgj, quant_format)
+    cfg = arch.of(cfgj).program_config(cfgj, quant_format)
     sset = serve_settings_for(cfg.name)
     prompt_max, out_max = traffic_mod.limits(traffic)
     return ServingEngine(

@@ -12,7 +12,6 @@ from chipbench import work
 
 # searched in each device op's name and metadata
 MATCH = r"^%(w4a16_fused|w4a16_decoupled|w8a16_fused|w4a8_fused)[.\d]* ="
-EXCLUDE = None
 
 
 def least_time(cfgj, steps, peaks) -> float:

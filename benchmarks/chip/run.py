@@ -28,7 +28,6 @@ T_START = time.perf_counter()
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -43,7 +42,7 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from chipbench import check as check_mod  # noqa: E402
+from chipbench import arch, check as check_mod  # noqa: E402
 from chipbench import serving, tracing, traffic as traffic_mod  # noqa: E402
 from chipbench import weights  # noqa: E402
 
@@ -95,11 +94,8 @@ class Context:
 
 def read_metric(ctx: Context, name: str):
     base = name.split(".")[0]
-    path = os.path.join(BENCH_DIR, "metrics", base + ".py")
-    spec = importlib.util.spec_from_file_location(f"metric_{base}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    return arch.load_file(os.path.join(BENCH_DIR, "metrics", base + ".py"),
+                          f"metric_{base}").read(ctx)
 
 
 def cell_metrics(bench: dict, cell: str, traced: bool) -> list:
@@ -146,7 +142,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     """One run of one cell; returns the result line as a dict.
 
     ``files`` (tests) replaces the cell's configuration, traffic and limits
-    files with dicts; ``peaks`` the peak-table row; ``quant_format`` the
+    files with dicts, and its architecture module with the file at
+    ``files["model"]``; ``peaks`` the peak-table row; ``quant_format`` the
     format the weights are handed to the program as; ``engine_hook`` is
     called with the engine before set-up; ``keep`` (a dict) receives the
     run's record, weights, sample and per-token gaps."""
@@ -158,16 +155,19 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
         BENCH_DIR, "traffic", cell["traffic"] + ".json")
     limits = files.get("limits") or load_json(
         BENCH_DIR, "limits", workload + ".json")
+    if "model" in files:
+        arch.load(cfgj["model"], files["model"])
+    model = arch.of(cfgj)
     dev = jax.devices()[0]
     if peaks is None:
         peaks = peaks_for(dev.device_kind)
     enable_compile_cache()
     counter = serving.compile_counter()
 
-    raw = weights.make_raw(cfgj, seed)
+    raw = model.make_raw(cfgj, seed)
     log(f"{workload}: weights {weights.nbytes(raw) / 1e9:.3f} GB from seed "
         f"{seed}")
-    params = weights.program_params(raw, cfgj, quant_format)
+    params = model.program_params(raw, cfgj, quant_format)
     engine = serving.build_engine(cfgj, mix, params, quant_format)
     if engine_hook is not None:
         engine_hook(engine)

@@ -3,11 +3,12 @@ sizes (the harness's look for a chip skipped): it passes the program on
 large seeds, traced and untraced, and fails the lower-precision controls
 and a broken timed path.
 
-Reduced sizes: 2 layers, width 128, 4/2 heads of 32, vocabulary 512,
-window 16, pages of 8, chunks of 8; four sessions of 20-70 tokens decoding
-160 more, so every session's ring wraps. The limits here sit between the
-program's readings on these sizes over the seeds below and the controls'
-(see LIMITS).
+Every cell is served at the sizes its architecture module's ``reduced()``
+gives, under the ``reduced`` block of its traffic file: for ``danube.long``
+2 layers, width 128, 4/2 heads of 32, vocabulary 512, window 16, pages of
+8, chunks of 8; four sessions of 20-70 tokens decoding 160 more, so every
+session's ring wraps. The limits here sit between the program's readings on
+these sizes over the seeds below and the controls' (see LIMITS).
 """
 import json
 import os
@@ -17,26 +18,16 @@ import numpy as np
 import pytest
 
 import benchpath  # noqa: F401
-from chipbench import check
+from chipbench import arch, check
 
 run = benchpath.load_run()
 
 SEEDS = (1319105951, 2 ** 31 + 5, 3000000019)
-REDUCED = dict(num_hidden_layers=2, hidden_size=128, intermediate_size=256,
-               num_attention_heads=4, num_key_value_heads=2, head_dim=32,
-               vocab_size=512, prefill_chunk=8, page_size=8)
 LIMITS = {"sample": {"min_tokens": 120, "max_positions": 4000},
           "max_logit_gap": {"limit": 0.1},
           "mean_logit_gap": {"limit": 0.01},
           "tokens_compared": {"limit": 60}}
 PEAKS = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
-MIXES = {
-    "danube.long": {
-        "arrivals": {"process": "sessions", "sessions": 4}, "slots": 4,
-        "prompt": {"dist": "lognormal", "median": 40, "sigma": 0.3,
-                   "min": 20, "max": 70},
-        "output": {"dist": "fixed", "value": 160, "min": 160, "max": 160}},
-}
 
 
 class StepClock:
@@ -63,12 +54,13 @@ def bench():
 
 
 def files(workload):
-    cell = next(w for w in bench()["workloads"] if w["name"] == workload)
-    cfg = run.load_json(run.BENCH_DIR, "configs", cell["config"] + ".json")
-    cfg.update(REDUCED)
-    if cfg["sliding_window"]:
-        cfg["sliding_window"] = 16
-    return {"config": cfg, "traffic": MIXES[workload], "limits": LIMITS}
+    b = bench()
+    cell = next(w for w in b["workloads"] if w["name"] == workload)
+    conf = next(c for c in b["configs"] if c["name"] == cell["config"])
+    cfg = run.load_json(run.ROOT, conf["file"])
+    mix = run.load_json(run.BENCH_DIR, "traffic", cell["traffic"] + ".json")
+    return {"config": arch.of(cfg).reduced(cfg), "traffic": mix["reduced"],
+            "limits": LIMITS}
 
 
 def serve(workload, seed, trace=False, hook=None, keep=None, seconds=1.5,
@@ -159,18 +151,23 @@ def test_without_a_chip_it_exits_2_and_prints_nothing(capsys):
 
 
 def test_benchmark_file_names_its_files():
+    """Every cell names a configuration whose file, architecture module and
+    reference exist, a traffic file with a ``reduced`` block, and limits;
+    every metric has its reader."""
     b = run.load_json(run.ROOT, "BENCHMARK.json")
-    names = [w["name"] for w in b["workloads"]]
-    assert names == ["danube.long"]
     for w in b["workloads"]:
         conf = next(c for c in b["configs"] if c["name"] == w["config"])
-        assert os.path.exists(os.path.join(run.ROOT, conf["file"]))
-        for sub, name in (("traffic", w["traffic"]), ("limits", w["name"])):
+        cfg = run.load_json(run.ROOT, conf["file"])
+        for sub, name, ext in (("models", cfg["model"], ".py"),
+                               ("configs", cfg["reference"], ".py"),
+                               ("limits", w["name"], ".json")):
             assert os.path.exists(os.path.join(run.BENCH_DIR, sub,
-                                               name + ".json"))
+                                               name + ext)), (sub, name)
+        mix = run.load_json(run.BENCH_DIR, "traffic", w["traffic"] + ".json")
+        assert "reduced" in mix, w["traffic"]
+        assert run.cell_metrics(b, w["name"], False)
+        assert run.cell_metrics(b, w["name"], True)
     for m in b["end_to_end"] + b["per_layer"]:
         base = m["name"].split(".")[0]
         assert os.path.exists(os.path.join(run.BENCH_DIR, "metrics",
                                            base + ".py"))
-    for w in names:
-        assert run.cell_metrics(b, w, False) and run.cell_metrics(b, w, True)

@@ -74,7 +74,7 @@ def test_roofline_assigns_ops_by_pattern():
         ops=[op("%w4a16_fused.57 = bf16[32,2560] custom-call(%a, %b), "
                 'custom_call_target="tpu_custom_call"', "m", 0.0, 0.002,
                 "", "d"),
-             op("%closed_call.13 = (f32[32,8,1,1,4,80]) custom-call(%q), "
+             op("%paged_attention.3 = (f32[32,8,1,1,4,80]) custom-call(%q), "
                 'custom_call_target="tpu_custom_call"', "m", 0.002, 0.0005,
                 "", "d"),
              op("%fusion.2 = bf16[32,2560] fusion(%x)", "m", 0.0025,
@@ -85,9 +85,10 @@ def test_roofline_assigns_ops_by_pattern():
     assert jobs.device_time(job, summary) == pytest.approx(0.002)
     attn = jobs.load(benchpath.BENCH_DIR, "paged_attention")
     assert jobs.device_time(attn, summary) == pytest.approx(0.0005)
-    cfgj = {"num_hidden_layers": 1, "hidden_size": 128,
-            "intermediate_size": 256, "num_attention_heads": 4,
-            "num_key_value_heads": 2, "head_dim": 32, "mlp": "gated"}
+    cfgj = {"model": "dense_decoder", "num_hidden_layers": 1,
+            "hidden_size": 128, "intermediate_size": 256,
+            "num_attention_heads": 4, "num_key_value_heads": 2,
+            "head_dim": 32, "mlp": "gated"}
     step = types.SimpleNamespace(decode_pos=[3, 4])
     peaks = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
     ctx = types.SimpleNamespace(trace=summary, bench_dir=benchpath.BENCH_DIR,
@@ -98,3 +99,20 @@ def test_roofline_assigns_ops_by_pattern():
     empty = tracing.TraceSummary([], [], [], 1.0, ["d"])
     ctx.trace = empty
     assert jobs.roofline_pct(ctx, "gemm_w4a16") is None
+
+
+@pytest.mark.parametrize("name, job", [
+    ("%paged_attention.3", "paged_attention"),
+    ("%w4a16_fused.57", "gemm_w4a16"),
+    ("%w4a16_grouped.1", None),
+    ("%closed_call.13", None),
+])
+def test_jobs_match_kernels_by_name(name, job):
+    """A custom call goes to the job that names its kernel, and one of any
+    other name to no job."""
+    op = tracing.Op(f"{name} = bf16[4,2560] custom-call(%a), "
+                    'custom_call_target="tpu_custom_call"', "m", 0.0, 0.001,
+                    "", "d")
+    for other in ("gemm_w4a16", "paged_attention"):
+        assert jobs.assigned(jobs.load(benchpath.BENCH_DIR, other), op) \
+            == (other == job)
